@@ -53,6 +53,7 @@ from repro.service import (DEADLINE_EXCEEDED, DurabilityConfig,
                            PriceSystemsRequest, PricingService, QUEUE_FULL,
                            RankRequest, RequestJournal, SearchRequest,
                            SearchWarmup, ServiceConfig, SHUTTING_DOWN)
+from repro.service.cache import use_compile_cache
 
 from .common import emit, write_bench_json
 from .dse_bench import SPACE
@@ -368,6 +369,7 @@ def main():
                     help="CI smoke: smaller sweeps and searches")
     ap.add_argument("--clients", type=int, default=6)
     args = ap.parse_args()
+    use_compile_cache()
     run(fast=args.fast, clients=args.clients)
 
 

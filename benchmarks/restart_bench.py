@@ -13,6 +13,8 @@ atexit hooks, no flushes, whatever was mid-write stays mid-write.
 It then recovers in-process over the same directory: a fresh service
 rescans the journal, re-admits the orphaned search with replayed
 provenance, restores the newest readable checkpoint, and finishes it.
+The parent runs nothing on JAX until the child is dead: on an
+accelerator only one process may hold the device.
 
 Asserts (and writes BENCH_restart.json for
 scripts/check_bench_regression.py):
@@ -34,20 +36,13 @@ import sys
 import tempfile
 import time
 
-import jax
-
-from repro.dse import portfolio_search
-from repro.service import (DurabilityConfig, PricingService,
-                           RequestJournal, SearchRequest, SearchWarmup,
-                           ServiceConfig)
-
 from .common import REPO_ROOT, emit, write_bench_json
-from .dse_bench import SPACE
 
 SEED, POP, ELITE = 3, 16, 4
 
 
-def _cfg(directory: pathlib.Path) -> ServiceConfig:
+def _cfg(directory: pathlib.Path):
+    from repro.service import DurabilityConfig, SearchWarmup, ServiceConfig
     return ServiceConfig(
         chunk=32, split=8,
         warm_search=(SearchWarmup(population=POP, elite=ELITE),),
@@ -58,6 +53,10 @@ def _cfg(directory: pathlib.Path) -> ServiceConfig:
 
 def child(directory: str, generations: int) -> None:
     """The victim: serve one long search until killed."""
+    from repro.service import PricingService, SearchRequest
+
+    from .dse_bench import SPACE
+
     async def _main():
         svc = PricingService(SPACE, _cfg(pathlib.Path(directory)))
         await svc.start()
@@ -82,7 +81,9 @@ def _published_steps(directory: pathlib.Path) -> int:
 
 
 def run(fast: bool = False, generations: int = 0, kill_after: int = 2,
-        timeout_s: float = 180.0) -> dict:
+        timeout_s: float = 900.0) -> dict:
+    """``timeout_s`` bounds the wait for ``kill_after`` checkpoints; it
+    covers the child's cold start, which compiles every warmed lane."""
     gens = generations or (300 if fast else 600)
     directory = pathlib.Path(tempfile.mkdtemp(prefix="repro_restart_"))
     env = dict(os.environ)
@@ -94,13 +95,19 @@ def run(fast: bool = False, generations: int = 0, kill_after: int = 2,
             [sys.executable, "-m", "benchmarks.restart_bench", "--child",
              "--dir", str(directory), "--generations", str(gens)],
             cwd=REPO_ROOT, env=env)
-        deadline = time.perf_counter() + timeout_s
+        t_spawn = time.perf_counter()
+        deadline = t_spawn + timeout_s
+        first_ckpt_s = None
         while True:
             if proc.poll() is not None:
                 raise RuntimeError(
                     f"child exited (rc={proc.returncode}) before the kill"
                     f" — raise --generations (got {gens})")
             steps = _published_steps(directory)
+            if steps and first_ckpt_s is None:
+                first_ckpt_s = time.perf_counter() - t_spawn
+                print(f"# restart: child published its first checkpoint "
+                      f"{first_ckpt_s:.2f}s after spawn")
             if steps >= kill_after:
                 break
             if time.perf_counter() > deadline:
@@ -115,6 +122,13 @@ def run(fast: bool = False, generations: int = 0, kill_after: int = 2,
         proc.wait()
 
         # -- recovery: a fresh service over the same directory ----------
+        import jax
+
+        from repro.dse import portfolio_search
+        from repro.service import PricingService, RequestJournal
+
+        from .dse_bench import SPACE
+
         async def _recover():
             svc = PricingService(SPACE, _cfg(directory))
             t0 = time.perf_counter()
@@ -145,6 +159,7 @@ def run(fast: bool = False, generations: int = 0, kill_after: int = 2,
     summary = {
         "generations": gens,
         "checkpoints_at_kill": checkpoints_at_kill,
+        "child_first_checkpoint_s": first_ckpt_s,
         "child_killed": 1,
         "replayed": len(replayed),
         "checkpoints_restored": snap["checkpoints_restored"],
@@ -184,6 +199,8 @@ def main():
     ap.add_argument("--kill-after", type=int, default=2,
                     help="published checkpoint steps before SIGKILL")
     args = ap.parse_args()
+    from repro.service.cache import use_compile_cache
+    use_compile_cache()
     if args.child:
         if not args.dir or not args.generations:
             ap.error("--child needs --dir and --generations")

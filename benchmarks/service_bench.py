@@ -44,6 +44,7 @@ from repro.service import (McSpec, MCRiskRequest, PriceRequest,
                            PriceSystemsRequest, PricingService, RankRequest,
                            SearchRequest, SearchWarmup, ServiceConfig,
                            WhatIfRequest)
+from repro.service.cache import use_compile_cache
 
 from .common import REPO_ROOT, emit, write_bench_json
 from .dse_bench import SPACE
@@ -267,6 +268,7 @@ def main():
                     help="enable the SLO/error-budget tracker and fold "
                          "its snapshot into BENCH_service.json")
     args = ap.parse_args()
+    use_compile_cache()
     run(fast=args.fast, clients=args.clients, slo=args.slo)
 
 

@@ -358,26 +358,14 @@ def _gen_step_impl(tables, key, pop, qty, mc_key, sig, *, meta: EncoderMeta,
     return pop, next_pop, elite_idx[0], elite_obj[0]
 
 
-# One module-level jit; the population buffer is donated so the
-# generation loop recycles device memory (donation is a no-op on backends
-# like CPU that do not implement it — gated to keep the warning away).
-# Built lazily: jax.default_backend() initializes the backend, which must
-# not happen as an import side effect.
-_GEN_STEP = None
-
-
-def _gen_step():
-    global _GEN_STEP
-    if _GEN_STEP is None:
-        donate = (2,) if jax.default_backend() != "cpu" else ()
-        _GEN_STEP = jaxhooks.instrument(
-            jax.jit(
-                _gen_step_impl,
-                static_argnames=("meta", "flow", "population", "elite",
-                                 "jump_prob", "n_draws", "quantile"),
-                donate_argnums=donate),
-            "search.gen_step", trace_key="gen_step", counts=TRACE_COUNTS)
-    return _GEN_STEP
+# One module-level jit, like the evaluator's chunk jits; the population
+# buffer is donated so the generation loop recycles device memory.
+_GEN_STEP_JIT = jaxhooks.instrument(
+    jax.jit(_gen_step_impl,
+            static_argnames=("meta", "flow", "population", "elite",
+                             "jump_prob", "n_draws", "quantile"),
+            donate_argnums=(2,)),
+    "search.gen_step", trace_key="gen_step", counts=TRACE_COUNTS)
 
 
 def portfolio_search(space: DesignSpace, key, *,
@@ -430,12 +418,11 @@ def portfolio_search(space: DesignSpace, key, *,
             restored = SearchState.restore_latest(manager, population)
             if restored is not None:
                 state = restored
-    step = _gen_step()
     label_fn = lambda i: space.candidate_at(i).label()  # noqa: E731
     for gen in range(state.gen, generations):
         with _TRACER.span("generation", gen=gen):
             state.k_loop, k_gen = jax.random.split(state.k_loop)
-            pop_out, pop_next, gen_idx, gen_obj = step(
+            pop_out, pop_next, gen_idx, gen_obj = _GEN_STEP_JIT(
                 enc.tables, k_gen, state.pop, qty, state.mc_key,
                 state.sig, meta=enc.meta,
                 flow=flow, population=population, elite=elite,

@@ -17,14 +17,39 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import json
+import os
+import pathlib
 from collections import OrderedDict
 from typing import Any, Dict, Hashable, Optional, Tuple
 
+import jax
 import numpy as np
 
 from ..core.engine import TRACE_COUNTS
 from ..dse.space import DesignSpace
 from ..obs.trace import TRACER
+
+# The checkout root: src/repro/service/cache.py -> parents[3].
+_CHECKOUT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Keep JAX's persistent compilation cache in a fixed directory, so a
+    cold start (which compiles every warmed lane) reuses what an earlier
+    process of this checkout compiled.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is changed here.  Otherwise the cache goes to
+    ``<checkout>/.jax_cache``: a fixed path, because the path is part of
+    what the cache is found by.  Call it from a program's ``main`` before
+    the first compile; never on import.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
 
 # TRACE_COUNTS keys that indicate device-kernel (re)compilation relevant
 # to the service's lanes.

@@ -48,7 +48,7 @@ from ..core.system import System, spec
 from ..dse.evaluate import _CHUNK_JIT, _CHUNK_MC_JIT, ChunkedEvaluator, \
     EvalArrays
 from ..dse.search import SearchResult, SearchState, _default_mc_key, \
-    _front, _gen_step, _rank
+    _front, _GEN_STEP_JIT, _rank
 from ..dse.space import ArchChoice, Candidate, DesignSpace
 from ..obs import jaxhooks
 from ..obs.flight import FlightRecorder
@@ -214,7 +214,7 @@ class SearchTask:
         next population stays on device)."""
         st = self.state
         st.k_loop, k_gen = jax.random.split(st.k_loop)
-        pop_out, pop_next, gen_idx, gen_obj = _gen_step()(
+        pop_out, pop_next, gen_idx, gen_obj = _GEN_STEP_JIT(
             self.svc.enc.tables, k_gen, st.pop, self.svc.qty,
             st.mc_key, st.sig, meta=self.svc.enc.meta,
             flow=self.sr.flow, population=self.sr.population,
@@ -504,7 +504,7 @@ class PricingService:
         _default_mc_key(key0)
         pop0 = jax.random.randint(k_init, (w.population,), 0,
                                   self.space.size(), dtype=jnp.int32)
-        self.traces.ensure(sig, lambda: jax.device_get(_gen_step()(
+        self.traces.ensure(sig, lambda: jax.device_get(_GEN_STEP_JIT(
             self.enc.tables, key0, pop0, self.qty, key0,
             jnp.zeros((4,), jnp.float32), meta=self.enc.meta, flow=flow,
             population=w.population, elite=w.elite,
@@ -1427,13 +1427,15 @@ class PricingService:
     def _tick_chunk(self, plan: TickPlan) -> int:
         k = self.cfg.chunk
         with _TRACER.span("pack", used=plan.used):
-            chunk_idx = np.zeros((k,), np.int64)
+            # int32 on the host: converting on the device would compile
+            # a convert program inside the first tick
+            chunk_idx = np.zeros((k,), np.int32)
             for a in plan.assignments:
                 chunk_idx[a.slot:a.slot + a.n] = \
                     a.item.idx[a.start:a.start + a.n]
             if plan.used < k and plan.assignments:
                 chunk_idx[plan.used:] = chunk_idx[0]  # cost-neutral padding
-            dev = jnp.asarray(chunk_idx, jnp.int32)
+            dev = jnp.asarray(chunk_idx)
         host = None
         degraded = False
         if self.breaker.allow():

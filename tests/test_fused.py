@@ -149,8 +149,11 @@ def test_fused_risk_stats_match_legacy_quantiles(space):
 
 
 def test_mc_re_draws_plus_nre_equals_full_mc(space):
-    """NRE is scenario-invariant: RE-only draws plus the one NRE row must
-    reproduce the full Monte-Carlo totals bit for bit."""
+    """NRE is scenario-invariant: RE-only draws plus the one NRE row
+    reproduce the full Monte-Carlo totals.  The identity is exact in real
+    arithmetic only: the two graphs sum RE and NRE in different orders,
+    and XLA (more so on a TPU) may reassociate those float32 adds, so the
+    results agree to an ulp or so, not bit for bit."""
     batch = encode_batch(space, np.arange(6))
     key = jax.random.PRNGKey(2)
     sig = np.asarray([0.2, 0.1, 0.25, 0.2], np.float32)
@@ -159,7 +162,7 @@ def test_mc_re_draws_plus_nre_equals_full_mc(space):
         lambda b, k: mc_re_totals_impl(b, k, sig, "chip-last", 32))(
         batch, key))
     nre = np.asarray(ENGINE.nre(batch).total)
-    np.testing.assert_array_equal(full, re_only + nre[None, :])
+    np.testing.assert_allclose(full, re_only + nre[None, :], rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------
