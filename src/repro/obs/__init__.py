@@ -5,16 +5,18 @@ The paper's argument is quantitative cost attribution; this package
 applies the same discipline to the stack's own compute cost.  Four
 surfaces, used by engine, DSE, search and service alike:
 
-* :mod:`repro.obs.trace` — nestable labeled spans (``pack`` /
-  ``jit_compile`` / ``kernel_dispatch`` / ``device_get`` / ``chunk`` /
-  ``generation`` / ``tick``), exportable as Chrome/Perfetto
-  ``trace_event`` JSON and aggregate per-phase wall tables;
+* :mod:`repro.obs.trace` — nestable labeled spans (``admit`` /
+  ``lower`` / ``tick`` / ``pack`` / ``device_wait`` / ``scatter`` /
+  ``finish`` / ``generation`` / ``chunk``), always emitted as
+  ``repro.<name>`` profiler annotations and, while tracing is on, kept
+  in a ring exportable as Chrome/Perfetto ``trace_event`` JSON and
+  aggregate per-phase wall tables;
 * :mod:`repro.obs.registry` — named counters/gauges/histograms with
   JSON + Prometheus-style text exposition (and the ``TRACE_COUNTS``
   compatibility shim);
 * :mod:`repro.obs.jaxhooks` — per-signature compile-vs-dispatch
-  attribution of the module-level jit entry points plus a
-  ``jax.device_get`` transfer hook;
+  attribution of the module-level jit entry points, and the totals of
+  the service's tick fetches;
 * :mod:`repro.obs.flight` — the service's bounded black-box ring,
   dumped as a trace file on error or on demand;
 * :mod:`repro.obs.ledger` — per-request serving-cost bills: each
@@ -25,10 +27,11 @@ surfaces, used by engine, DSE, search and service alike:
   per request kind with sliding-window error-budget burn rates; a burn
   excursion latches a flight-recorder auto-dump.
 
-Tracing is **off by default and zero-cost when off**; turn it on with
-``REPRO_TRACE=1`` in the environment or :func:`enable`.  It never adds
-host syncs and never retraces a warmed signature (pinned by the
-trace-count oracle in ``tests/test_obs.py``).
+Tracing is **off by default**; turn it on with ``REPRO_TRACE=1`` in the
+environment or :func:`enable`.  Off, a span still enters its profiler
+annotation, 0.53 us on a TPU v5e host's CPU (PERF.md), and the probes
+forward untimed.  It never adds host syncs and never retraces a warmed signature
+(pinned by the trace-count oracle in ``tests/test_obs.py``).
 """
 from __future__ import annotations
 
@@ -54,13 +57,9 @@ def enabled() -> bool:
 
 
 def enable(on: bool = True):
-    """Turn span tracing + the jit probes + the device_get hook on/off
-    at runtime (the programmatic twin of ``REPRO_TRACE=1``)."""
+    """Turn the span ring and the jit probes on/off at runtime (the
+    programmatic twin of ``REPRO_TRACE=1``)."""
     TRACER.enable(on)
-    if on:
-        jaxhooks.install_device_get_hook()
-    else:
-        jaxhooks.uninstall_device_get_hook()
 
 
 def disable():
@@ -77,9 +76,3 @@ def phase_table():
     """Aggregate per-phase wall table (count/total/mean/max seconds)."""
     return TRACER.phase_table()
 
-
-# REPRO_TRACE=1 in the environment enables the full layer at import —
-# the tracer itself already read the env var; finish the job by
-# installing the device_get hook.
-if TRACER.enabled():
-    jaxhooks.install_device_get_hook()

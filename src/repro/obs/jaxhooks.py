@@ -1,5 +1,5 @@
-"""JAX cost attribution: per-signature compile/dispatch probes and a
-``jax.device_get`` hook.
+"""JAX cost attribution: per-signature compile/dispatch probes and the
+totals of the service's tick fetches.
 
 The stack's hot paths are module-level ``jax.jit`` entry points
 (``repro.core.engine``, ``repro.dse.evaluate`` / ``search`` /
@@ -14,15 +14,15 @@ measured, queryable one: ``stats()`` reports compiles per signature and
 :func:`recompiles_since` / the tracer's ``jit_compile``-inside-``tick``
 count expose any warm-path retrace.
 
-:func:`install_device_get_hook` wraps ``jax.device_get`` so every
-device->host sync is counted and its transferred bytes summed — the
-third axis (transfer) next to compile and dispatch.
+:func:`device_get_stats` reads the fetch counters the service keeps
+at its tick syncs (``service/metrics.py``): calls, bytes and the wall
+spent waiting in them — the third axis (transfer) next to compile and
+dispatch.  ``jax.device_get`` itself is never replaced.
 
-Everything is **off while tracing is off**: probes forward with a single
-predicate check, and the device_get hook is only installed by
-:func:`repro.obs.enable`.  Probes never call ``block_until_ready`` —
-dispatch time is the host-side dispatch wall, device waits show up where
-they always did, in ``device_get``.
+Probes are **off while tracing is off**: they forward with a single
+predicate check.  Probes never call ``block_until_ready`` — dispatch
+time is the host-side dispatch wall; device waits show up where they
+always did, in the tick's fetch.
 """
 from __future__ import annotations
 
@@ -170,10 +170,12 @@ def total_compiles() -> int:
 
 def total_dispatch_s() -> float:
     """Total probe-attributed jit wall (compile + steady-state dispatch)
-    across every probe.  Deltas of this marker give the measured "device
-    ms inside this tick" the serving-cost ledger pro-rates per request.
-    Only meaningful while tracing is on (probes forward untimed when
-    off); callers fall back to tick wall otherwise."""
+    across every probe.  Deltas of this marker give the host seconds
+    spent enqueueing (or compiling) programs inside a tick, which the
+    serving-cost ledger pro-rates per request; the device's own time is
+    not in it (the probe returns before the device finishes).  Only
+    meaningful while tracing is on (probes forward untimed when off);
+    callers fall back to tick wall otherwise."""
     total = 0.0
     for p in _PROBES:
         s = p.summary()
@@ -187,58 +189,10 @@ def recompiles_since(marker: int) -> int:
     return total_compiles() - marker
 
 
-# ---------------------------------------------------------------------------
-# device_get hook: count syncs + transferred bytes
-# ---------------------------------------------------------------------------
-
-_ORIG_DEVICE_GET: Optional[Callable] = None
-
-
-def _tree_nbytes(x) -> int:
-    leaves, _ = jax.tree_util.tree_flatten(x)
-    return sum(int(getattr(l, "nbytes", 0)) for l in leaves)
-
-
-def install_device_get_hook():
-    """Patch ``jax.device_get`` so every device->host transfer records a
-    ``device_get`` span plus call/byte counters.  Idempotent."""
-    global _ORIG_DEVICE_GET
-    if _ORIG_DEVICE_GET is not None:
-        return
-    orig = jax.device_get
-    _ORIG_DEVICE_GET = orig
-    calls = REGISTRY.counter("device_get_calls",
-                             help="jax.device_get invocations")
-    nbytes = REGISTRY.counter("device_get_bytes",
-                              help="bytes transferred device->host")
-    wall = REGISTRY.counter("device_get_s",
-                            help="wall seconds inside jax.device_get")
-
-    def traced_device_get(x):
-        t0 = perf_counter()
-        out = orig(x)
-        dt = perf_counter() - t0
-        b = _tree_nbytes(out)
-        trace.TRACER.add_complete("device_get", dt, bytes=b)
-        calls.inc()
-        nbytes.inc(b)
-        wall.inc(dt)
-        return out
-
-    traced_device_get._repro_obs_hook = True
-    jax.device_get = traced_device_get
-
-
-def uninstall_device_get_hook():
-    """Restore the original ``jax.device_get``."""
-    global _ORIG_DEVICE_GET
-    if _ORIG_DEVICE_GET is not None:
-        jax.device_get = _ORIG_DEVICE_GET
-        _ORIG_DEVICE_GET = None
-
-
 def device_get_stats() -> Dict[str, float]:
-    """Totals collected by the device_get hook (zeros if never installed)."""
+    """Totals of the service's tick fetches: ``jax.device_get`` calls,
+    bytes brought to the host and seconds waited in them (zeros before
+    the first tick)."""
     def val(name):
         m = REGISTRY.get(name)
         return m.get() if m is not None else 0.0

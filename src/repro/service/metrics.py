@@ -8,8 +8,11 @@ Three measurement surfaces:
   stream), split by request kind.
 * **ticks** — slot occupancy vs padded waste per device tick — reported
   **per lane** (chunk / mc / gen / raw) and in aggregate, so search
-  (``gen``) work is no longer a blind spot — plus the
-  one-``device_get``-per-tick invariant counter.
+  (``gen``) work is no longer a blind spot — plus the count of tick
+  fetches (one ``device_get`` per tick).
+* **where a request's time goes** — admission wall, queue wait before
+  its first tick, and the wait in each tick's fetch, counted where the
+  work happens (host clock differences and integer adds, always on).
 * **caches/traces** — result-cache hit rates and post-warmup recompile
   counts (folded in from the cache layer at snapshot time).
 
@@ -41,6 +44,8 @@ class RequestRecord:
     ok: bool = True
     cached: bool = False
     trace_id: str = ""
+    t_admitted: float = 0.0            # end of admission (enqueued only)
+    queue_wait_s: Optional[float] = None   # admitted -> first tick
 
     @property
     def latency_s(self) -> float:
@@ -193,12 +198,18 @@ class ServiceMetrics:
         self.n_errors = 0
         self.n_rejected = 0                  # backpressure rejections
         self.ticks = 0
-        self.device_gets = 0
         self.slots_used = 0
         self.slots_total = 0
         self.gen_ticks = 0
         self.rows_priced = 0                 # candidate rows through kernels
         self.busy_s = 0.0                    # wall inside ticks
+        self.admit_s = 0.0                   # admission wall, enqueued only
+        self.admitted = 0
+        self.queue_wait_s = 0.0              # admitted -> first tick
+        self.queue_waited = 0
+        self.device_wait_s = 0.0             # wall inside the tick fetches
+        self.device_get_calls = 0
+        self.device_get_bytes = 0
         self.per_lane: Dict[str, LaneStats] = {}
         self.t_start = time.perf_counter()
 
@@ -235,6 +246,50 @@ class ServiceMetrics:
                                help="ok-request latency").observe(
                 rec.latency_s, exemplar=rec.trace_id or None)
 
+    def record_admit(self, rec: RequestRecord, t_admitted: float):
+        """A request was enqueued: its admission ran from submit to
+        ``t_admitted`` (validation, lowering, cache lookup, scheduler
+        admit, journal write).  Cache hits and rejections never come
+        here."""
+        rec.t_admitted = t_admitted
+        wall = t_admitted - rec.t_submit
+        self.admit_s += wall
+        self.admitted += 1
+        REGISTRY.counter("service_admit_s",
+                         help="admission wall of enqueued requests").inc(
+            wall)
+        REGISTRY.counter("service_admitted",
+                         help="requests enqueued").inc()
+
+    def record_ride(self, rec: RequestRecord, t_tick: float):
+        """A tick starting at ``t_tick`` carries work of ``rec``; its
+        first such tick ends the request's queue wait (counted once)."""
+        if rec.queue_wait_s is not None or not rec.t_admitted:
+            return
+        wait = max(0.0, t_tick - rec.t_admitted)
+        rec.queue_wait_s = wait
+        self.queue_wait_s += wait
+        self.queue_waited += 1
+        REGISTRY.counter("service_queue_wait_s",
+                         help="admission to first tick, summed").inc(wait)
+        REGISTRY.counter("service_queue_waited",
+                         help="requests that rode a tick").inc()
+
+    def record_fetch(self, wait_s: float, nbytes: int):
+        """One tick sync: ``wait_s`` inside ``jax.device_get``, ``nbytes``
+        brought to the host."""
+        self.device_wait_s += wait_s
+        self.device_get_calls += 1
+        self.device_get_bytes += nbytes
+        REGISTRY.counter("device_get_calls",
+                         help="tick jax.device_get calls").inc()
+        REGISTRY.counter("device_get_bytes",
+                         help="bytes the tick fetches brought to the "
+                              "host").inc(nbytes)
+        REGISTRY.counter("device_get_s",
+                         help="wall seconds inside the tick "
+                              "fetches").inc(wait_s)
+
     # -- tick accounting -----------------------------------------------------
     def record_tick(self, lane_kind: str, slots: int, used: int,
                     rows_priced: int, wall_s: float):
@@ -243,7 +298,6 @@ class ServiceMetrics:
         them — search work counts toward occupancy and rows like every
         other lane instead of being silently excluded."""
         self.ticks += 1
-        self.device_gets += 1        # the tick loop does exactly one get
         self.busy_s += wall_s
         self.rows_priced += rows_priced
         self.slots_used += used
@@ -278,7 +332,7 @@ class ServiceMetrics:
             "latency_s": _quantiles([r.latency_s for r in ok]),
             "ttfr_s": _quantiles([r.ttfr_s for r in ok]),
             "ticks": self.ticks,
-            "device_gets": self.device_gets,
+            "device_gets": self.device_get_calls,
             "gen_ticks": self.gen_ticks,
             "ticks_by_lane": {k: v.ticks for k, v in self.per_lane.items()},
             "per_lane": {k: v.as_dict() for k, v in self.per_lane.items()},
@@ -288,6 +342,12 @@ class ServiceMetrics:
                                   if self.slots_total else 0.0),
             "rows_priced": self.rows_priced,
             "busy_s": self.busy_s,
+            "admit_s": self.admit_s,
+            "admitted": self.admitted,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_waited": self.queue_waited,
+            "device_wait_s": self.device_wait_s,
+            "device_get_bytes": self.device_get_bytes,
             "rows_per_sec_busy": (self.rows_priced / self.busy_s
                                   if self.busy_s > 0 else 0.0),
             "wall_s": time.perf_counter() - self.t_start,

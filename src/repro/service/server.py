@@ -783,29 +783,47 @@ class PricingService:
         ``_replaces`` / ``_trace_id`` are the journal-replay path's
         internals (see :meth:`_replay_journal`); client code never
         passes them."""
+        with _TRACER.span("admit"):
+            admitted = self._admit(request, on_partial, replayed_from,
+                                   _replaces, _trace_id)
+        if isinstance(admitted, Response):
+            return admitted
+        try:
+            return await admitted.future
+        except asyncio.CancelledError:
+            self._cancel(admitted)
+            raise
+
+    def _admit(self, request: Request, on_partial, replayed_from,
+               replaces: Optional[int], trace_id: Optional[str]):
+        """The synchronous part of :meth:`submit`: validation, lowering,
+        the cache lookup, the scheduler's admit and the journal write.
+        Returns the envelope of a request answered here (rejection or
+        cache hit), else the enqueued request."""
+        t_submit = time.perf_counter()
         self._uid += 1
         uid = self._uid
         # the request-scoped correlation id: minted here at admission,
         # preserved verbatim across journal replay so one logical request
         # keeps ONE trace across process restarts.
-        trace_id = _trace_id or mint_trace_id()
-        t_submit = time.perf_counter()
+        trace_id = trace_id or mint_trace_id()
         self.log.event(uid, "submit", kind=request.kind,
                        trace_id=trace_id)
         _TRACER.instant("request_admit", trace_id=trace_id, uid=uid,
                         kind=request.kind)
         if not self._accepting:
-            self._journal_replaced(_replaces, SHUTTING_DOWN)
+            self._journal_replaced(replaces, SHUTTING_DOWN)
             return self._reject(uid, request, t_submit, trace_id,
                                 SHUTTING_DOWN, "service is shutting down",
                                 replayed_from)
         self._ensure_loop()
         try:
-            active, items, cached = self._lower(uid, request, t_submit,
-                                                on_partial, replayed_from,
-                                                trace_id)
+            with _TRACER.span("lower"):
+                active, items, cached = self._lower(
+                    uid, request, t_submit, on_partial, replayed_from,
+                    trace_id)
         except ServiceError as e:
-            self._journal_replaced(_replaces, e.code)
+            self._journal_replaced(replaces, e.code)
             return self._reject(uid, request, t_submit, trace_id,
                                 e.code, str(e), replayed_from)
         if cached is not None:
@@ -815,7 +833,7 @@ class PricingService:
             _TRACER.instant("request_done", trace_id=trace_id, uid=uid,
                             kind=request.kind, cached=True)
             self.log.event(uid, "cache_hit")
-            self._journal_replaced(_replaces, "ok")
+            self._journal_replaced(replaces, "ok")
             now = time.perf_counter()
             return Response(request_id=uid, kind=request.kind, ok=True,
                             result=cached, cached=True,
@@ -827,7 +845,7 @@ class PricingService:
         flood = self._fire("flood")
         if flood is not None or not self.sched.admit(items, active.cost):
             self.metrics.reject()
-            self._journal_replaced(_replaces, QUEUE_FULL)
+            self._journal_replaced(replaces, QUEUE_FULL)
             return self._reject(
                 uid, request, t_submit, trace_id, QUEUE_FULL,
                 "pending row budget exhausted (injected flood)"
@@ -849,16 +867,13 @@ class PricingService:
             # duplicates work, never loses it.
             self.journal.admit(uid, request_to_wire(request, self.space),
                                origin=active.origin, trace_id=trace_id)
-            if _replaces is not None:
-                self.journal.done(_replaces, "replayed")
+            if replaces is not None:
+                self.journal.done(replaces, "replayed")
         self.log.event(uid, "admitted", rows=active.n_rows)
         if self._wake is not None:
             self._wake.set()
-        try:
-            return await active.future
-        except asyncio.CancelledError:
-            self._cancel(active)
-            raise
+        self.metrics.record_admit(active.rec, time.perf_counter())
+        return active
 
     # ------------------------------------------------------------------
     # Lowering: request -> lane + work items + finalizers
@@ -1245,8 +1260,9 @@ class PricingService:
         # a finishing request's bill includes its final tick's share.
         self._tick_done = []
         self._raw_parts = None
-        span_labels: Dict[str, object] = {"lane": plan.lane.kind}
+        span_labels: Dict[str, object] = {}
         if _TRACER.enabled():
+            span_labels["lane"] = plan.lane.kind
             tids, seen = [], set()
             for owner in self._owners(plan):
                 if owner.trace_id and owner.trace_id not in seen:
@@ -1280,6 +1296,8 @@ class PricingService:
                 self.watchdog.exit()
         recompiled = self.traces.meter_tick(before)
         wall = time.perf_counter() - t0
+        for owner in self._owners(plan):
+            self.metrics.record_ride(owner.rec, t0)
         # gen lanes price their whole population every tick: count those
         # rows as fully-occupied slots so search work shows up in
         # occupancy instead of being excluded (see ServiceMetrics).
@@ -1301,8 +1319,10 @@ class PricingService:
             self.log.event(-1, "tick_recompile", lane=plan.lane.kind,
                            traces=recompiled)
         done, self._tick_done = self._tick_done, []
-        for fin in done:
-            fin()
+        if done:
+            with _TRACER.span("finish"):
+                for fin in done:
+                    fin()
         return True
 
     def _tick_parts(self, plan: TickPlan) -> List[Tuple[Bill, int]]:
@@ -1335,11 +1355,15 @@ class PricingService:
         return parts
 
     def _owners(self, plan: TickPlan) -> List[_Active]:
+        """The requests riding ``plan`` (raw groups shed back to the
+        queue during the tick do not ride it)."""
+        groups = self._raw_parts if self._raw_parts is not None \
+            else plan.groups
         owners = []
         if plan.gen is not None:
             owners.append(plan.gen.owner)
         owners += [a.item.owner for a in plan.assignments]
-        owners += [g.owner for g in plan.groups]
+        owners += [g.owner for g in groups]
         return owners
 
     def _fail_tick(self, plan: TickPlan, err: Exception):
@@ -1379,7 +1403,19 @@ class PricingService:
         else:
             out = _CHUNK_JIT(self.enc.tables, dev, self.qty,
                              meta=self.enc.meta, flow=lane.flow)
-        return jax.device_get(out)                 # THE tick sync
+        return self._fetch(out)
+
+    def _fetch(self, out):
+        """THE tick sync: ``jax.device_get`` of the tick's outputs, its
+        wait and bytes counted (``ServiceMetrics.record_fetch``)."""
+        with _TRACER.span("device_wait"):
+            t0 = time.perf_counter()
+            host = jax.device_get(out)
+            wait = time.perf_counter() - t0
+            self.metrics.record_fetch(wait, sum(
+                int(getattr(x, "nbytes", 0))
+                for x in jax.tree_util.tree_leaves(host)))
+        return host
 
     def _dispatch_fused_with_retry(self, lane: Lane, dev):
         """Returns ``(host, None)`` or, with the retry budget spent,
@@ -1454,6 +1490,13 @@ class PricingService:
             self.res.bump("fallback_ticks")
             self.res.bump("fallback_rows", plan.used)
             self.res.bump("fallback_busy_s", time.perf_counter() - t_fb)
+        with _TRACER.span("scatter"):
+            self._scatter_chunk(plan, host, degraded)
+        return plan.used
+
+    def _scatter_chunk(self, plan: TickPlan, host, degraded: bool):
+        """Copy each assignment's rows of the fetched chunk into its
+        request; completions are deferred to the end of the tick."""
         now = time.perf_counter()
         unit, re_t, nre_t, pf = host[0], host[1], host[2], host[3]
         risk = host[4] if plan.lane.kind == "mc" else None
@@ -1503,9 +1546,6 @@ class PricingService:
                 # the bill before the response envelope snapshots it
                 self._tick_done.append(
                     lambda r=req: self._finish_sweep(r))
-        if _TRACER.enabled():
-            _TRACER.add_complete("scatter", time.perf_counter() - now)
-        return plan.used
 
     def _tick_gen(self, plan: TickPlan) -> int:
         work: GenWork = plan.gen
@@ -1531,8 +1571,7 @@ class PricingService:
             return 0
         with _TRACER.span("generation", gen=task.gen):
             try:
-                out = task.device_call()
-                host = jax.device_get(out)         # THE tick sync
+                host = self._fetch(task.device_call())
             except Exception as e:
                 self._fail(req, INTERNAL_ERROR, f"{type(e).__name__}: {e}")
                 return 0
@@ -1580,7 +1619,14 @@ class PricingService:
                 return 0
             self._raw_parts = list(groups)   # actual riders after shedding
             padded = pad_batch(batch, **self.raw_pad)
-        host = jax.device_get(_TOTAL_JIT(padded, plan.lane.flow))  # THE sync
+        host = self._fetch(_TOTAL_JIT(padded, plan.lane.flow))
+        with _TRACER.span("scatter"):
+            return self._scatter_raw(groups, host)
+
+    def _scatter_raw(self, groups: List[GroupWork], host) -> int:
+        """Build each group's rows from the fetched totals; completions
+        are deferred to the end of the tick.  Returns the systems
+        priced."""
         now = time.perf_counter()
         total = np.asarray(host.total, np.float64)
         re_tot = np.asarray(host.re.total, np.float64)
@@ -1611,8 +1657,6 @@ class PricingService:
             req.rows_done = req.n_rows
             self._tick_done.append(
                 lambda r=req, p=SystemsResult(rows=rows): self._finish(r, p))
-        if _TRACER.enabled():
-            _TRACER.add_complete("scatter", time.perf_counter() - now)
         return off
 
     # ------------------------------------------------------------------

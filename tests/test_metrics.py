@@ -82,7 +82,8 @@ def test_gen_ticks_count_toward_occupancy():
     assert snap["padded_waste_frac"] == pytest.approx(1 - 40 / 48)
     assert snap["rows_priced"] == 40
     assert snap["ticks"] == 2 and snap["gen_ticks"] == 1
-    assert snap["device_gets"] == 2
+    # fetches are counted where they happen, not per recorded tick
+    assert snap["device_gets"] == 0
     assert snap["busy_s"] == pytest.approx(0.030)
 
 
@@ -127,7 +128,8 @@ EXPECTED_KEYS = {
     "requests_by_kind", "latency_s", "ttfr_s", "ticks", "device_gets",
     "gen_ticks", "ticks_by_lane", "per_lane", "slot_occupancy",
     "padded_waste_frac", "rows_priced", "busy_s", "rows_per_sec_busy",
-    "wall_s",
+    "wall_s", "admit_s", "admitted", "queue_wait_s", "queue_waited",
+    "device_wait_s", "device_get_bytes",
 }
 
 

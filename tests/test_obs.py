@@ -1,8 +1,9 @@
-"""repro.obs: span tracer semantics (off-by-default no-op, nesting /
+"""repro.obs: span tracer semantics (ring off by default, nesting /
 parent attribution, Chrome export), metrics registry + TraceCounts shim,
-JitProbe compile-vs-dispatch attribution, the device_get hook, the
-flight recorder ring, and the trace-count oracle — tracing a warmed
-fused sweep must not retrace anything and must leave results bit-exact.
+JitProbe compile-vs-dispatch attribution, the service's fetch counters
+(``jax.device_get`` itself never replaced), the flight recorder ring,
+and the trace-count oracle — tracing a warmed fused sweep must not
+retrace anything and must leave results bit-exact.
 """
 import json
 import time
@@ -16,7 +17,7 @@ from repro import obs
 from repro.obs import jaxhooks
 from repro.obs.flight import FlightRecorder
 from repro.obs.registry import Registry, REGISTRY, TraceCounts
-from repro.obs.trace import TRACER, Tracer, _NULL_SPAN
+from repro.obs.trace import TRACER, Tracer
 
 
 @pytest.fixture
@@ -38,7 +39,8 @@ def traced():
 
 def test_disabled_tracer_is_noop():
     tr = Tracer(enabled=False)
-    assert tr.span("tick") is _NULL_SPAN
+    with tr.span("tick"):
+        assert tr.events() == [] and tr._stack() == []
     with tr.span("tick", lane="chunk"):
         pass
     tr.add_complete("kernel_dispatch", 0.5)
@@ -113,7 +115,10 @@ def test_enable_disable_runtime_toggle():
         assert obs.enabled()
         obs.disable()
         assert not obs.enabled()
-        assert TRACER.span("x") is _NULL_SPAN
+        n = len(TRACER.events())
+        with TRACER.span("x"):
+            pass
+        assert len(TRACER.events()) == n
     finally:
         TRACER.clear()
         obs.enable(was)
@@ -250,27 +255,43 @@ def test_jit_probe_disabled_is_passthrough():
         jaxhooks._PROBES.remove(probe)
 
 
-def test_device_get_hook_counts_calls_and_bytes(traced):
-    # `traced` installed the hook via obs.enable()
+def test_device_get_hook_counts_calls_and_bytes():
+    """The tick sync counts its calls and bytes where it happens: one
+    fetch per tick, of the chunk's five outputs."""
+    from repro.dse import DesignSpace, SKU
+    from repro.service import PriceRequest, ServiceConfig, serve
+
+    space = DesignSpace(
+        skus=(SKU("a", 200.0, 1e6),), processes=("7nm",),
+        integrations=("MCM",), chiplet_counts=(1, 2), allow_reuse=False)
     before = jaxhooks.device_get_stats()
-    x = jnp.arange(16, dtype=jnp.float32)
-    host = jax.device_get(x)
-    assert np.array_equal(host, np.arange(16, dtype=np.float32))
+    resps, svc = serve(space, [PriceRequest(indices=[0, 1])],
+                       ServiceConfig(chunk=8, raw_slots=0,
+                                     fallback=False))
+    assert resps[0].ok, resps[0].error
     after = jaxhooks.device_get_stats()
-    assert after["calls"] == before["calls"] + 1
-    assert after["bytes"] == before["bytes"] + 64
-    assert TRACER.count("device_get") >= 1
+    m = svc.metrics
+    assert m.ticks == 1 and m.device_get_calls == 1
+    # unit/re/nre (8, 1) float32, portfolio cost (8,) float32, finite (8,)
+    assert m.device_get_bytes == 3 * 8 * 4 + 8 * 4 + 8
+    assert after["calls"] - before["calls"] == 1
+    assert after["bytes"] - before["bytes"] == m.device_get_bytes
+    assert after["total_s"] - before["total_s"] == \
+        pytest.approx(m.device_wait_s)
+    assert 0 < m.device_wait_s <= m.busy_s
 
 
-def test_device_get_hook_uninstall_restores():
-    obs.enable()
-    hooked = jax.device_get
-    assert getattr(hooked, "_repro_obs_hook", False)
-    obs.disable()
-    assert not getattr(jax.device_get, "_repro_obs_hook", False)
-    # double-uninstall is harmless
-    jaxhooks.uninstall_device_get_hook()
-    TRACER.clear()
+def test_device_get_is_never_replaced():
+    from jax._src.api import device_get as jax_own
+    was = obs.enabled()
+    try:
+        obs.enable()
+        assert jax.device_get is jax_own
+        obs.disable()
+        assert jax.device_get is jax_own
+    finally:
+        TRACER.clear()
+        obs.enable(was)
 
 
 # ---------------------------------------------------------------------------
