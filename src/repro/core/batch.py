@@ -8,11 +8,14 @@ paper's full RE + NRE model (Eqs. 4-8) for the whole batch in one jitted,
 vmap/grad-compatible trace — the design-space-sweep representation the
 scalar ``System`` dataclasses cannot provide.
 
-Construction happens host-side (cheap, once per sweep shape); everything
-after ``from_systems`` / ``from_specs`` is pure array math.  All float
-leaves may be swapped (``dataclasses.replace``) for traced values, which
-is how the differentiable partitioner sweeps areas/quantities without
-rebuilding the batch.
+Construction happens host-side (cheap, once per sweep shape):
+``SystemBatch.pack`` builds the leaves as numpy arrays, and
+``from_systems`` / ``from_specs`` move them to the device in one batched
+``jax.device_put`` (a padded batch moves as one buffer,
+``SystemBatch.to_device``); everything after that is pure array math.
+All float leaves may be swapped (``dataclasses.replace``) for traced
+values, which is how the differentiable partitioner sweeps
+areas/quantities without rebuilding the batch.
 
 NRE amortization structure (who shares which design entity) is encoded as
 integer id arrays + flat (instance -> system) index maps so the Eq. (6)-(8)
@@ -40,18 +43,21 @@ retained jit trace.
 from __future__ import annotations
 
 import dataclasses
+import functools
+import math
 from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..resilience.guards import validate_packed_arrays
 from .system import System, spec
 from .technology import node, tech
 
-_FLOAT = jnp.float32
-_INT = jnp.int32
+_FLOAT = np.float32
+_INT = np.int32
 
 
 @jax.tree_util.register_pytree_node_class
@@ -142,20 +148,58 @@ class SystemBatch:
     def __len__(self) -> int:
         return self.n_systems
 
+    def to_device(self) -> "SystemBatch":
+        """A host batch on the default device in ONE host-to-device
+        transfer: the float32 / int32 leaves are laid end to end as one
+        int32 buffer (floats as their bits), put once, and split into the
+        leaves by one small program (:func:`_split_words`).  That program
+        compiles once per signature, so this is the move for batches whose
+        shapes repeat (:func:`pad_batch` products); a small transfer costs
+        a TPU host about as much as a large one.  The leaves come out
+        bit-identical and uncommitted, as ``jnp.asarray`` leaves them;
+        names are kept."""
+        leaves = [np.asarray(getattr(self, f)) for f in self._LEAVES]
+        bad = [f for f, a in zip(self._LEAVES, leaves)
+               if a.dtype not in (np.float32, np.int32)]
+        if bad:
+            raise ValueError(f"to_device needs float32/int32 leaves: {bad}")
+        words = np.concatenate([a.reshape(-1).view(np.int32)
+                                for a in leaves])
+        layout = tuple((a.shape, a.dtype == np.float32) for a in leaves)
+        return SystemBatch(*_split_words(jax.device_put(words), layout),
+                           names=self.names)
+
     # -- constructors --------------------------------------------------------
     @classmethod
     def from_systems(cls, systems: Sequence[System],
                      max_chips: Optional[int] = None,
                      share_nre: Union[bool, Sequence[int]] = True,
                      ) -> "SystemBatch":
-        """Pack :class:`System` objects into one batch.
+        """Pack :class:`System` objects into one batch on the device.
 
-        ``share_nre=True`` amortizes design entities across the whole batch
-        (the batch is one product group, as in ``amortized_costs``) and
-        therefore requires unique system names; ``share_nre=False`` prices
-        each system as a standalone group.  A sequence of integer group
-        ids (one per system) shares entities within each group only —
-        names must be unique within a group.
+        The leaves are built on the host by :meth:`pack` and moved by one
+        batched ``jax.device_put`` of the leaf tree, which compiles nothing
+        for a new shape.  ``share_nre=True`` amortizes design entities
+        across the whole batch (the batch is one product group, as in
+        ``amortized_costs``) and therefore requires unique system names;
+        ``share_nre=False`` prices each system as a standalone group.  A
+        sequence of integer group ids (one per system) shares entities
+        within each group only — names must be unique within a group.
+        """
+        host = cls.pack(systems, max_chips=max_chips, share_nre=share_nre)
+        return cls(*jax.device_put(tuple(getattr(host, f)
+                                         for f in cls._LEAVES)),
+                   names=host.names)
+
+    @classmethod
+    def pack(cls, systems: Sequence[System],
+             max_chips: Optional[int] = None,
+             share_nre: Union[bool, Sequence[int]] = True,
+             ) -> "SystemBatch":
+        """:meth:`from_systems` on the host: the same leaves (float32 /
+        int32 numpy arrays, the same entity numbering, the same
+        ``validate_packed_arrays`` guard), with nothing sent to the device.
+        Pad it with :func:`pad_batch` and move it with :meth:`to_device`.
         """
         systems = list(systems)
         if not systems:
@@ -277,8 +321,7 @@ class SystemBatch:
                 "invalid system parameters: " + "; ".join(problems))
 
         def arr(x, dt=_FLOAT):
-            return jnp.asarray(np.asarray(x, dtype=np.float32
-                                          if dt is _FLOAT else np.int32))
+            return np.asarray(x, dtype=dt)
 
         chip_rows = np.asarray(chip_ent_rows, np.float32).reshape(-1, 3)
         pkg_rows = np.asarray(pkg_ent_rows, np.float32).reshape(-1, 3)
@@ -398,6 +441,21 @@ SystemBatch._LEAVES = tuple(
     if fld.name != "names")
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _split_words(words, layout):
+    """The leaves laid end to end in ``words`` (int32) by
+    :meth:`SystemBatch.to_device`; ``layout`` holds each leaf's shape and
+    whether it is float32 (sent as its bits)."""
+    out, off = [], 0
+    for shape, is_float in layout:
+        n = math.prod(shape)
+        x = lax.slice(words, (off,), (off + n,)).reshape(shape)
+        out.append(lax.bitcast_convert_type(x, jnp.float32) if is_float
+                   else x)
+        off += n
+    return tuple(out)
+
+
 # ---------------------------------------------------------------------------
 # Constant-shape padding — the enabler of chunked evaluation (repro.dse).
 # ---------------------------------------------------------------------------
@@ -433,14 +491,25 @@ def pad_batch(b: SystemBatch, *, n_systems: Optional[int] = None,
     compiled :class:`~repro.core.engine.CostEngine` trace, which is how
     ``repro.dse.evaluate`` prices unbounded candidate streams through
     constant-shape chunks without retracing.
+
+    Padding is numpy work on the host, and the result lives where ``b``
+    did.  A host batch (:meth:`SystemBatch.pack`) never touches the
+    device here: the caller moves the padded batch itself, in one
+    transfer (:meth:`SystemBatch.to_device`).  A batch with device leaves
+    is fetched with one ``jax.device_get`` of the whole tree and the
+    padded batch goes back in one transfer.
     """
-    n0, c0 = b.chip_area.shape
-    ec0 = b.chip_entity_area.shape[0]
-    ep0 = b.pkg_entity_area.shape[0]
-    em0 = b.mod_entity_area.shape[0]
-    m0 = b.mod_sys.shape[0]
-    ed0 = b.d2d_entity_nre.shape[0]
-    d0 = b.d2d_sys.shape[0]
+    leaves = {f: getattr(b, f) for f in SystemBatch._LEAVES}
+    on_device = any(isinstance(x, jax.Array) for x in leaves.values())
+    if on_device:
+        leaves = jax.device_get(leaves)
+    n0, c0 = leaves["chip_area"].shape
+    ec0 = leaves["chip_entity_area"].shape[0]
+    ep0 = leaves["pkg_entity_area"].shape[0]
+    em0 = leaves["mod_entity_area"].shape[0]
+    m0 = leaves["mod_sys"].shape[0]
+    ed0 = leaves["d2d_entity_nre"].shape[0]
+    d0 = leaves["d2d_sys"].shape[0]
     tgt = {
         "n_systems": (n0, n0 if n_systems is None else int(n_systems)),
         "max_chips": (c0, c0 if max_chips is None else int(max_chips)),
@@ -473,21 +542,19 @@ def pad_batch(b: SystemBatch, *, n_systems: Optional[int] = None,
             "padding instances requires a padded entity row or a padded "
             "system to absorb them")
 
-    def _np(x):
-        return np.asarray(jax.device_get(x))
+    def pad1(a, size, value=0.0):
+        out = np.full(size, value, a.dtype)
+        out[:a.shape[0]] = a
+        return out
 
-    def pad1(x, size, value=0.0):
-        a = _np(x)
-        return np.pad(a, (0, size - a.shape[0]), constant_values=value)
-
-    def pad2(x, value=0.0):
-        a = _np(x)
-        return np.pad(a, ((0, n1 - n0), (0, c1 - c0)),
-                      constant_values=value)
+    def pad2(a, value=0.0):
+        out = np.full((n1, c1), value, a.dtype)
+        out[:n0, :c0] = a
+        return out
 
     out = {}
-    for f in SystemBatch._LEAVES:
-        a = getattr(b, f)
+    for f, a in leaves.items():
+        a = np.asarray(a)
         val = 1.0 if f in _PAD_ONE else 0.0
         if f == "chip_entity_id":
             out[f] = pad2(a, 0)
@@ -519,5 +586,5 @@ def pad_batch(b: SystemBatch, *, n_systems: Optional[int] = None,
     names = b.names
     if names:
         names = tuple(names) + tuple(f"__pad{i}" for i in range(n1 - n0))
-    return SystemBatch(**{k: jnp.asarray(v) for k, v in out.items()},
-                       names=names)
+    padded = SystemBatch(**out, names=names)
+    return padded.to_device() if on_device else padded

@@ -13,7 +13,7 @@ Python — the >=30x candidate-throughput path ``benchmarks/dse_bench.py``
 pins.
 
 The original host-packing path (``candidate_systems`` +
-``SystemBatch.from_systems`` + :func:`~repro.core.batch.pad_batch`) is
+``SystemBatch.pack`` + :func:`~repro.core.batch.pad_batch`) is
 retained behind ``fused=False`` as the parity oracle; both paths produce
 chunks with identical array signatures and therefore share one compiled
 engine trace.
@@ -385,7 +385,8 @@ class ChunkedEvaluator:
     # -- legacy host-packing path (parity oracle) ---------------------------
     def pack_chunk(self, chunk: Sequence[Candidate]) -> SystemBatch:
         """Pack <= candidates_per_chunk candidates into one padded batch
-        via the host ``System`` route (reference path)."""
+        via the host ``System`` route (reference path): packed and padded
+        on the host, then moved to the device in one transfer."""
         if len(chunk) > self.shape.candidates:
             raise ValueError(f"chunk of {len(chunk)} exceeds "
                              f"{self.shape.candidates} candidates")
@@ -394,9 +395,9 @@ class ChunkedEvaluator:
             grp = candidate_systems(self.space, cand)
             systems += grp
             groups += [j] * len(grp)
-        batch = SystemBatch.from_systems(systems, share_nre=groups,
-                                         max_chips=self.shape.max_chips)
-        return pad_batch(batch, **self.shape.pad_kwargs())
+        batch = SystemBatch.pack(systems, share_nre=groups,
+                                 max_chips=self.shape.max_chips)
+        return pad_batch(batch, **self.shape.pad_kwargs()).to_device()
 
     def _legacy_chunk_host(self, chunk: Sequence[Candidate], mc_key,
                            mc_draws: int, mc_sigmas) -> Tuple:

@@ -210,6 +210,8 @@ class ServiceMetrics:
         self.device_wait_s = 0.0             # wall inside the tick fetches
         self.device_get_calls = 0
         self.device_get_bytes = 0
+        self.raw_pack_s = 0.0                # raw ticks: entry -> dispatched
+        self.raw_packs = 0
         self.per_lane: Dict[str, LaneStats] = {}
         self.t_start = time.perf_counter()
 
@@ -290,6 +292,18 @@ class ServiceMetrics:
                          help="wall seconds inside the tick "
                               "fetches").inc(wait_s)
 
+    def record_raw_pack(self, wall_s: float):
+        """One raw tick's pack step, from entering the raw lane's tick to
+        the return of its dispatch: pack, shed and pad on the host, the
+        one transfer of the padded tables, the dispatch."""
+        self.raw_pack_s += wall_s
+        self.raw_packs += 1
+        REGISTRY.counter("service_raw_pack_s",
+                         help="raw tick wall up to its dispatch").inc(
+            wall_s)
+        REGISTRY.counter("service_raw_packs",
+                         help="raw ticks packed").inc()
+
     # -- tick accounting -----------------------------------------------------
     def record_tick(self, lane_kind: str, slots: int, used: int,
                     rows_priced: int, wall_s: float):
@@ -348,6 +362,8 @@ class ServiceMetrics:
             "queue_waited": self.queue_waited,
             "device_wait_s": self.device_wait_s,
             "device_get_bytes": self.device_get_bytes,
+            "raw_pack_s": self.raw_pack_s,
+            "raw_packs": self.raw_packs,
             "rows_per_sec_busy": (self.rows_priced / self.busy_s
                                   if self.busy_s > 0 else 0.0),
             "wall_s": time.perf_counter() - self.t_start,

@@ -517,9 +517,12 @@ class PricingService:
         def compile_raw():
             s = spec({"kind": "soc", "name": "__warm", "area": 100.0,
                       "process": self.space.processes[0], "quantity": 1.0})
-            b = SystemBatch.from_systems([s], share_nre=[0],
-                                         max_chips=self.raw_max_chips)
-            jax.device_get(_TOTAL_JIT(pad_batch(b, **self.raw_pad), flow))
+            # the tick's own path (host pack and pad, one transfer), so
+            # the compiled signature is the one every raw tick dispatches
+            b = SystemBatch.pack([s], share_nre=[0],
+                                 max_chips=self.raw_max_chips)
+            jax.device_get(_TOTAL_JIT(
+                pad_batch(b, **self.raw_pad).to_device(), flow))
 
         self.traces.ensure(sig, compile_raw, trace_id=trace_id)
 
@@ -1206,8 +1209,9 @@ class PricingService:
                     raise ValueError(
                         f"system {s.name!r} has {s.n_chips} chips "
                         f"(raw lane limit {self.raw_max_chips})")
-            # dry-run the solo pack: catches duplicate names, bad specs
-            solo = SystemBatch.from_systems(
+            # dry-run the solo pack on the host: catches duplicate names,
+            # bad specs and groups over the lane's entity budget
+            solo = SystemBatch.pack(
                 systems, share_nre=[0] * len(systems),
                 max_chips=self.raw_max_chips)
             if not self._raw_fits(solo):
@@ -1601,6 +1605,10 @@ class PricingService:
         return task.sr.population
 
     def _tick_raw(self, plan: TickPlan) -> int:
+        """Pack, shed and pad on the host, move the padded tables to the
+        device in one transfer, dispatch, and fetch the totals in the
+        tick's one ``jax.device_get``."""
+        t0 = time.perf_counter()
         with _TRACER.span("pack", lane="raw"):
             groups = list(plan.groups)
             # combined entity tables must fit the padded signature; shed
@@ -1610,7 +1618,7 @@ class PricingService:
                 for gi, g in enumerate(groups):
                     systems += g.systems
                     gids += [gi] * g.n_systems
-                batch = SystemBatch.from_systems(
+                batch = SystemBatch.pack(
                     systems, share_nre=gids, max_chips=self.raw_max_chips)
                 if self._raw_fits(batch):
                     break
@@ -1618,8 +1626,10 @@ class PricingService:
             if not groups:
                 return 0
             self._raw_parts = list(groups)   # actual riders after shedding
-            padded = pad_batch(batch, **self.raw_pad)
-        host = self._fetch(_TOTAL_JIT(padded, plan.lane.flow))
+            padded = pad_batch(batch, **self.raw_pad).to_device()
+        out = _TOTAL_JIT(padded, plan.lane.flow)
+        self.metrics.record_raw_pack(time.perf_counter() - t0)
+        host = self._fetch(out)
         with _TRACER.span("scatter"):
             return self._scatter_raw(groups, host)
 

@@ -1,11 +1,15 @@
 """repro.dse: padded-chunk parity with the direct engine path, constant
 trace counts across chunk boundaries, seeded search determinism, and the
 exhaustive-enumeration cross-check of the portfolio optimizer."""
+import json
+import pathlib
+
 import jax
 import numpy as np
 import pytest
 
 from repro.core import CostEngine, SystemBatch, pad_batch, split_system
+from repro.core.system import spec
 from repro.core.engine import TRACE_COUNTS
 from repro.dse import (Candidate, ChunkedEvaluator, DesignSpace, SKU,
                        Uncertainty, candidate_systems, chunk_shape,
@@ -140,6 +144,74 @@ def test_pad_batch_refuses_to_shrink_or_strand_instances():
     with pytest.raises(ValueError):
         # more instances but nowhere harmless to park them
         pad_batch(batch, mod_instances=batch.mod_sys.shape[0] + 2)
+
+
+# The five raw spec groups of the benchmark's Fig. 8 points cell, padded to
+# that cell's raw-lane signature (raw_slots 16, raw_max_chips 4).
+_FIG8_GROUPS = next(
+    m["params"]["groups"] for m in json.loads(
+        (pathlib.Path(__file__).resolve().parents[1] / "chipbench"
+         / "workloads" / "scms_fig8.points.json").read_text())["open"]["mix"]
+    if m["kind"] == "price_systems")
+_RAW_PAD = dict(n_systems=16, max_chips=4, chip_entities=65, pkg_entities=17,
+                mod_entities=129, mod_instances=128, d2d_entities=65,
+                d2d_instances=64)
+
+
+def _mixed_systems():
+    """D2D modules, chiplet and package reuse inside each of two NRE
+    groups that also share entity names across the groups."""
+    def reuse(name, n, integration):
+        return spec({"kind": "chips", "name": name, "integration": integration,
+                     "quantity": 2e5 * n, "package_name": "pkg",
+                     "package_area": 900.0,
+                     "chips": [{"name": "tile", "area": 150.0,
+                                "process": "7nm"}] * n})
+    group = [reuse("r1", 1, "MCM"), reuse("r3", 3, "MCM"),
+             split_system("h", 500.0, "5nm", 2, "2.5D", quantity=4e5),
+             spec({"kind": "soc", "name": "m", "area": 120.0,
+                   "process": "12nm", "quantity": 1e6})]
+    return group + group, [0] * 4 + [1] * 4
+
+
+_PACK_CASES = [
+    pytest.param([spec(dict(d)) for d in g], [0] * len(g), _RAW_PAD,
+                 id=f"fig8_group{i}")
+    for i, g in enumerate(_FIG8_GROUPS)] + [
+    pytest.param(*_mixed_systems(),
+                 dict(n_systems=11, max_chips=5, chip_entities=10,
+                      pkg_entities=8, mod_entities=10, mod_instances=17,
+                      d2d_entities=6, d2d_instances=15), id="mixed")]
+
+
+@pytest.mark.parametrize("systems,groups,pad", _PACK_CASES)
+def test_host_pack_and_pad_equal_the_device_path(systems, groups, pad):
+    """Packing and padding on the host gives, leaf for leaf, the arrays of
+    the device path (device leaves from ``from_systems``, padded through
+    ``pad_batch``'s fetch and put): same dtypes, values and names."""
+    host = pad_batch(SystemBatch.pack(systems, share_nre=groups, max_chips=4),
+                     **pad)
+    dev = pad_batch(SystemBatch.from_systems(systems, share_nre=groups,
+                                             max_chips=4), **pad)
+    assert host.names == dev.names
+    for f in SystemBatch._LEAVES:
+        a, b = getattr(host, f), getattr(dev, f)
+        assert isinstance(a, np.ndarray) and isinstance(b, jax.Array), f
+        b = np.asarray(jax.device_get(b))
+        assert a.dtype == b.dtype and a.dtype in (np.float32, np.int32), f
+        assert np.array_equal(a, b), f
+    # every axis really was padded
+    assert host.chip_area.shape == (pad["n_systems"], pad["max_chips"])
+    assert host.mod_sys.shape[0] == pad["mod_instances"]
+    assert host.d2d_sys.shape[0] == pad["d2d_instances"]
+    if groups[-1]:
+        with pytest.raises(ValueError):     # only float32/int32 travel
+            host.replace(quantity=host.quantity.astype(np.float64)) \
+                .to_device()
+        # "tile" is one design inside each group, two across the groups
+        ids = host.chip_entity_id
+        assert ids[0, 0] == ids[1, 2] != ids[4, 0] == ids[5, 2]
+        assert host.pkg_entity_id[0] != host.pkg_entity_id[4]
 
 
 def test_share_nre_groups_match_independent_shared_batches():

@@ -129,7 +129,7 @@ EXPECTED_KEYS = {
     "gen_ticks", "ticks_by_lane", "per_lane", "slot_occupancy",
     "padded_waste_frac", "rows_priced", "busy_s", "rows_per_sec_busy",
     "wall_s", "admit_s", "admitted", "queue_wait_s", "queue_waited",
-    "device_wait_s", "device_get_bytes",
+    "device_wait_s", "device_get_bytes", "raw_pack_s", "raw_packs",
 }
 
 

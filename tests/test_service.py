@@ -10,15 +10,16 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core import CostEngine, SystemBatch
+from repro.core import CostEngine, SystemBatch, pad_batch
 from repro.core.engine import TRACE_COUNTS
-from repro.core.system import spec
+from repro.core.system import Module, make_chip, spec
 from repro.dse import (ChunkedEvaluator, DesignSpace, RiskConfig, SKU,
                        Uncertainty, portfolio_search)
 from repro.service import (INVALID_REQUEST, Lane, McSpec, MCRiskRequest,
                            PriceRequest, PriceSystemsRequest, PricingService,
                            QUEUE_FULL, RankRequest, Scheduler, SearchRequest,
                            ServiceConfig, SpanWork, WhatIfRequest, serve)
+from repro.service import server as server_mod
 from repro.service.server import PricingService as _PS
 
 
@@ -179,6 +180,120 @@ def test_raw_systems_lane(space):
     for i, row in enumerate(rows):
         assert row["system"] == systems[i].name
         np.testing.assert_allclose(row["total"], direct[i], rtol=1e-6)
+
+
+def _wide_group(name):
+    """One two-chip system whose chips carry five modules each: 10 module
+    instances, so one group fits a raw lane of 4 slots x 2 chips (16
+    instances) and two groups do not."""
+    chips = [make_chip(f"{name}_c{j}",
+                       [Module(f"{name}_c{j}_m{k}", 20.0 + k, "7nm")
+                        for k in range(5)], "7nm", integration="MCM")
+             for j in range(2)]
+    return ({"kind": "chips", "name": name, "chips": chips,
+             "integration": "MCM", "quantity": 1e5},)
+
+
+def test_raw_lane_bit_exact_one_transfer_each_way(space, monkeypatch):
+    """A raw group is priced bit for bit like ``CostEngine`` on the same
+    group at the lane's padded signature, alone in its tick and when a
+    tick sheds a group that does not fit; admission touches no device
+    array, and a raw tick makes one host-to-device transfer, one dispatch
+    of device tables and one ``jax.device_get``."""
+    cfg = ServiceConfig(chunk=16, split=4, raw_slots=4, raw_max_chips=2,
+                        fallback=False)
+    soc = ({"kind": "soc", "name": "a", "area": 150.0, "process": "7nm",
+            "quantity": 1e6},
+           {"kind": "split", "name": "b", "area": 300.0, "process": "7nm",
+            "n_chiplets": 2, "integration": "MCM", "quantity": 5e5})
+    groups = [soc, _wide_group("w1"), _wide_group("w2")]
+    calls = {"get": 0, "put": 0, "dispatch": 0, "compile": 0}
+    seen = {"admit": [], "raw": []}
+    real_get, real_put = jax.device_get, jax.device_put
+    real_total = server_mod._TOTAL_JIT
+
+    def get(x):
+        calls["get"] += 1
+        return real_get(x)
+
+    def put(x, *a, **k):
+        calls["put"] += 1
+        return real_put(x, *a, **k)
+
+    def total(batch, flow):
+        calls["dispatch"] += 1
+        assert all(isinstance(x, jax.Array)
+                   for x in jax.tree_util.tree_leaves(batch))
+        return real_total(batch, flow)
+
+    def counted(fn, key):
+        def wrapper(*a, **k):
+            before = dict(calls)
+            # a raw group's admission moves nothing to or from the device
+            raw = isinstance(a[0], PriceSystemsRequest)
+            with jax.transfer_guard("disallow_explicit" if raw
+                                    else "allow"):
+                out = fn(*a, **k)
+            seen[key].append({c: calls[c] - before[c] for c in calls})
+            return out
+        return wrapper
+
+    def compiled(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            calls["compile"] += 1
+
+    async def _main():
+        svc = PricingService(space, cfg)
+        await svc.start()
+        jax.monitoring.register_event_duration_secs_listener(compiled)
+        monkeypatch.setattr(jax, "device_get", get)
+        monkeypatch.setattr(jax, "device_put", put)
+        monkeypatch.setattr(server_mod, "_TOTAL_JIT", total)
+        svc._admit = counted(svc._admit, "admit")
+        svc._tick_raw = counted(svc._tick_raw, "raw")
+        alone = await svc.submit(PriceSystemsRequest(specs=groups[0]))
+        # both wide groups are queued before the loop runs: the first
+        # tick sheds the second back to the queue
+        shed = await asyncio.gather(
+            *(svc.submit(PriceSystemsRequest(specs=g)) for g in groups[1:]),
+            svc.submit(PriceRequest(indices=[0, 1, 2])))
+        await svc.stop()
+        return svc, [alone, *shed[:2]], shed[2]
+
+    try:
+        svc, resps, chunk_resp = asyncio.run(_main())
+    finally:
+        jax.monitoring.unregister_event_duration_listener(compiled)
+    monkeypatch.undo()
+    assert calls["compile"] == 0           # warm-up compiled the raw lane
+    assert chunk_resp.ok and all(r.ok for r in resps), \
+        [r.error for r in resps]
+    raw_ticks = [t for t in svc.flight.records("tick") if t["lane"] == "raw"]
+    assert [(t["used"], t["rows"]) for t in raw_ticks] == \
+        [(2, 2), (2, 1), (1, 1)]                      # the middle one shed
+    assert [(d["get"], d["put"], d["dispatch"]) for d in seen["admit"][:3]] \
+        == [(0, 0, 0)] * 3
+    assert [(d["get"], d["put"], d["dispatch"]) for d in seen["raw"]] == \
+        [(1, 1, 1)] * 3
+    snap = svc.snapshot()
+    assert snap["raw_packs"] == 3 < snap["ticks"]
+    assert snap["raw_pack_s"] > 0.0
+
+    engine = CostEngine()
+    for g, resp in zip(groups, resps):
+        systems = [spec(dict(d)) for d in g]
+        batch = pad_batch(SystemBatch.from_systems(
+            systems, share_nre=[0] * len(systems), max_chips=2),
+            **svc.raw_pad)
+        tc = jax.device_get(engine.total(batch, flow="chip-last"))
+        n = len(systems)
+        rows = resp.result.rows
+        assert [r["system"] for r in rows] == [s.name for s in systems]
+        for key, want in (("re_total", tc.re.total),
+                          ("nre_total", tc.nre.total),
+                          ("total", tc.total)):
+            got = np.asarray([r[key] for r in rows])
+            assert np.array_equal(got, np.asarray(want[:n], np.float64)), key
 
 
 # ---------------------------------------------------------------------------

@@ -106,7 +106,7 @@ def _main_path_lowering(name, svc, chip):
             jump_prob=0.15, n_draws=0, quantile=0.5)
     assert name == "raw_total"
     group = [spec(dict(d)) for d in chip_smoke.RAW_GROUPS[0]]
-    batch = pad_batch(SystemBatch.from_systems(
+    batch = pad_batch(SystemBatch.pack(
         group, share_nre=[0] * len(group), max_chips=svc.raw_max_chips),
         **svc.raw_pad)
     return _TOTAL_JIT.fn.lower(_shaped(batch, chip), flow)
