@@ -8,8 +8,24 @@ or per-layer metric is a file of its own under ``chipbench/``:
 * ``requests/<kind>.py``         generator and comparison of one kind
 * ``metrics/<metric>.py``        reader of one per-layer metric; a split
   quantity (``tick_ms.tput``, ``tick_ms.lat``) may share ``tick_ms.py``
+* ``spaces/<kind>.py``           schema of a configuration's ``space``
+  block, found by its ``kind`` (``grid`` where it names none)
 
-so a later cell, kind or metric is added as files, with no edit here.
+so a later cell, kind, metric or space schema is added as files, with no
+edit here.
+
+A space kind file holds two things:
+
+* ``build(space_cfg)``: the program's space object (the one
+  ``PricingService`` serves), importing the program inside the function;
+* ``Decoder(space_cfg)``: the reference's own reading of the candidate
+  order, importing nothing of the program.  It has ``size`` (candidates),
+  ``processes`` and ``integrations`` (the what-if menus),
+  ``candidate(i)`` (a hashable description of index ``i``),
+  ``index(cand)`` (its inverse, None outside the space),
+  ``systems(cand)`` (the candidate's systems as the plain dicts
+  ``reference.py`` prices, in SKU order) and ``swap(cand, process,
+  integration)`` (the what-if grid's move).
 """
 from __future__ import annotations
 
@@ -20,6 +36,28 @@ from types import ModuleType
 from typing import Dict, List
 
 BENCH_DIR = pathlib.Path(__file__).resolve().parents[1]
+
+
+def load(bench_dir: pathlib.Path, path: pathlib.Path) -> ModuleType:
+    """Runs the file ``path`` under ``bench_dir`` as a module."""
+    name = "chipbench_" + "_".join(path.relative_to(bench_dir).with_suffix(
+        "").parts).replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def space_kind_file(kind: str, bench_dir: pathlib.Path) -> pathlib.Path:
+    path = pathlib.Path(bench_dir) / "spaces" / f"{kind}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no space kind file {path}")
+    return path
+
+
+def space_kind(kind: str) -> ModuleType:
+    """The space schema ``spaces/<kind>.py`` of this benchmark."""
+    return load(BENCH_DIR, space_kind_file(kind, BENCH_DIR))
 
 
 class Catalog:
@@ -38,12 +76,7 @@ class Catalog:
     def _module(self, path: pathlib.Path) -> ModuleType:
         mod = self._modules.get(path)
         if mod is None:
-            name = "chipbench_" + "_".join(path.relative_to(self.dir).with_suffix(
-                "").parts).replace(".", "_").replace("-", "_")
-            spec = importlib.util.spec_from_file_location(name, path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            self._modules[path] = mod
+            mod = self._modules[path] = load(self.dir, path)
         return mod
 
     def cell(self, name: str) -> Dict:
@@ -64,6 +97,9 @@ class Catalog:
         if not path.is_file():
             raise FileNotFoundError(f"no request kind file {path}")
         return self._module(path)
+
+    def space_kind(self, kind: str) -> ModuleType:
+        return self._module(space_kind_file(kind, self.dir))
 
     def metric_reader(self, name: str) -> ModuleType:
         own = self.dir / "metrics" / f"{name}.py"

@@ -12,14 +12,16 @@ itself, so a served row is compared against what that index means.
   package defects and the known-good dies that packaging destroys.
 * Eqs. (6)-(8): NRE of every design entity (module, chip, package, D2D
   interface per node) amortized over the units that use it in a group.
-* Sec. 5: a design space's candidates are per-SKU architectures (mixed
-  radix, SKU 0 most significant) followed by one-chiplet reuse schemes.
+* Sec. 5: a design space's candidate order is read by the decoder of
+  its schema, ``spaces/<kind>.py`` (``grid`` where the configuration's
+  ``space`` names no ``kind``); the decoder gives each candidate's
+  systems as plain dicts and this module prices them.
 * Monte-Carlo risk: lognormal multipliers on defect density, wafer
   price, bond failure rates and interposer defects, one scenario per
   draw shared by every candidate, drawn from JAX's PRNG key of the
   request's seed (``split(key, draws)``, then ``split(k, 5)`` per draw).
 
-``Reference(space)`` computes in float64 with NumPy.  ``xp``/``dtype``
+``Reference(space_cfg)`` computes in float64 with NumPy.  ``xp``/``dtype``
 let the same arithmetic run in a lower precision (``jax.numpy`` in
 bfloat16) for the control that the comparison has to reject.
 """
@@ -27,13 +29,14 @@ from __future__ import annotations
 
 import json
 import pathlib
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from .catalog import space_kind
+
 TECH = json.loads(
     (pathlib.Path(__file__).with_name("technology.json")).read_text())
-_REL_TOL = 1e-6
 D2D = "__d2d__"
 
 
@@ -112,114 +115,6 @@ def spec_system(d: Dict) -> Dict:
 
 
 # ---------------------------------------------------------------------------
-# The design space's candidate order
-# ---------------------------------------------------------------------------
-
-class Space:
-    """Candidate decoding of a design space given as the configuration's
-    ``space`` block (SKUs, menus, reuse flags)."""
-
-    def __init__(self, cfg: Dict):
-        self.skus = [(s["name"], float(s["area"]), float(s["quantity"]))
-                     for s in cfg["skus"]]
-        self.processes = list(cfg["processes"])
-        self.integrations = list(cfg["integrations"])
-        self.counts = sorted(set(cfg["chiplet_counts"]))
-        self.within_sku = bool(cfg.get("reuse_within_sku", True))
-        self.arch = ([(1, p, "SoC") for p in self.processes]
-                     if 1 in self.counts else [])
-        self.arch += [(n, p, t) for n in self.counts if n > 1
-                      for p in self.processes for t in self.integrations]
-        self.reuse = []
-        if cfg.get("allow_reuse", True):
-            self.reuse = [(a, p, t, bool(pkg)) for a in self._slices()
-                          for p in self.processes for t in self.integrations
-                          for pkg in cfg.get("reuse_package_options",
-                                             [False])]
-        self.n_arch = len(self.arch) ** len(self.skus)
-        self.size = self.n_arch + len(self.reuse)
-
-    def _tiles(self, area: float, a: float) -> Optional[int]:
-        k = area / a
-        if abs(k - round(k)) > _REL_TOL * max(k, 1.0) \
-                or int(round(k)) not in self.counts:
-            return None
-        return int(round(k))
-
-    def _slices(self) -> List[float]:
-        out: List[float] = []
-        for a in sorted({s[1] / n for s in self.skus for n in self.counts},
-                        reverse=True):
-            if all(self._tiles(s[1], a) for s in self.skus) \
-                    and not any(abs(a - b) <= _REL_TOL * a for b in out):
-                out.append(a)
-        return out
-
-    def candidate(self, i: int):
-        """``("arch", ((n, process, integration), ...))`` or
-        ``("reuse", (slice_mm2, process, integration, package_reuse))``."""
-        if not 0 <= i < self.size:
-            raise IndexError(i)
-        if i >= self.n_arch:
-            return ("reuse", self.reuse[i - self.n_arch])
-        digits = []
-        for _ in self.skus:
-            i, d = divmod(i, len(self.arch))
-            digits.append(self.arch[d])
-        return ("arch", tuple(reversed(digits)))
-
-    def index(self, cand) -> Optional[int]:
-        """Index of a candidate, or None where it is not in the space."""
-        kind, body = cand
-        if kind == "reuse":
-            return (self.n_arch + self.reuse.index(body)
-                    if body in self.reuse else None)
-        i = 0
-        for c in body:
-            if c not in self.arch:
-                return None
-            i = i * len(self.arch) + self.arch.index(c)
-        return i
-
-    def systems(self, cand) -> List[Dict]:
-        kind, body = cand
-        if kind == "reuse":
-            a, p, t, pkg = body
-            counts = [self._tiles(s[1], a) for s in self.skus]
-            cname = f"reuse_{p}_{t}_{a:g}mm2"
-            chip = _chip(cname, [(f"{cname}_modules", a, p)], p, t)
-            pname = parea = None
-            if pkg:
-                pname = f"{cname}_pkg{max(counts)}s"
-                parea = (chip["area"] * max(counts)
-                         * TECH["integrations"][t]["package_area_factor"])
-            return [_system(nm, [chip] * k, t, q, pname, parea)
-                    for (nm, _, q), k in zip(self.skus, counts)]
-        out = []
-        for (nm, area, q), (n, p, t) in zip(self.skus, body):
-            if n == 1:
-                out.append(spec_system({"kind": "soc", "name": nm,
-                                        "area": area, "process": p,
-                                        "quantity": q}))
-            else:
-                out.append(spec_system({"kind": "split", "name": nm,
-                                        "area": area, "process": p, "n": n,
-                                        "integration": t, "quantity": q,
-                                        "reuse_chiplet": self.within_sku}))
-        return out
-
-    @staticmethod
-    def swap(cand, process: str, integration: str):
-        """The what-if grid's move: same architecture, another node and
-        packaging (a monolithic SKU stays SoC)."""
-        kind, body = cand
-        if kind == "reuse":
-            return ("reuse", (body[0], process, integration, body[3]))
-        return ("arch", tuple((n, process, "SoC" if n == 1 else integration)
-                              for n, _, _ in body))
-
-
-# ---------------------------------------------------------------------------
 # Costs
 # ---------------------------------------------------------------------------
 
@@ -227,8 +122,12 @@ class Reference:
     """Per-unit RE + amortized NRE of system groups, in one precision."""
 
     def __init__(self, space_cfg: Optional[Dict] = None, xp=np,
-                 dtype=np.float64):
-        self.space = Space(space_cfg) if space_cfg is not None else None
+                 dtype=np.float64, kinds: Callable = space_kind):
+        """``space_cfg`` is a configuration's ``space`` block; its
+        candidates are read by ``kinds(kind).Decoder`` (the catalog's
+        ``spaces/<kind>.py``)."""
+        self.space = (kinds(space_cfg.get("kind", "grid")).Decoder(space_cfg)
+                      if space_cfg is not None else None)
         self.xp, self.dt = xp, dtype
         self._cand: Dict[int, Dict] = {}
         self._mult: Dict[Tuple, Dict] = {}
@@ -339,10 +238,11 @@ class Reference:
         candidate ``i``, as float64 NumPy (memoized)."""
         got = self._cand.get(i)
         if got is None:
-            g = self.group(self.space.systems(self.space.candidate(i)))
+            systems = self.space.systems(self.space.candidate(i))
+            g = self.group(systems)
             got = {k: np.asarray([float(x) for x in v]) for k, v in g.items()}
             got["unit"] = got.pop("total")
-            qty = [self.c(s[2]) for s in self.space.skus]
+            qty = [self.c(s["quantity"]) for s in systems]
             got["pf"] = float(sum(q * self.c(u) for q, u in
                                   zip(qty, got["unit"])))
             self._cand[i] = got

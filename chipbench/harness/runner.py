@@ -20,7 +20,7 @@ from typing import Callable, Dict, List, Optional
 import numpy as np
 
 from . import check, drive, reduce, traffic
-from .catalog import Catalog
+from .catalog import Catalog, space_kind
 from .reference import Reference
 
 # Published peaks per chip, keyed by JAX's ``device_kind``.  A device
@@ -98,20 +98,10 @@ def use_cache():
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
-def design_space(cfg: Dict):
-    from repro.dse import SKU, DesignSpace
-
-    s = cfg["space"]
-    return DesignSpace(
-        skus=tuple(SKU(k["name"], float(k["area"]), float(k["quantity"]))
-                   for k in s["skus"]),
-        processes=tuple(s["processes"]),
-        integrations=tuple(s["integrations"]),
-        chiplet_counts=tuple(s["chiplet_counts"]),
-        allow_reuse=bool(s.get("allow_reuse", True)),
-        reuse_package_options=tuple(s.get("reuse_package_options",
-                                          [False])),
-        reuse_within_sku=bool(s.get("reuse_within_sku", True)))
+def design_space(cfg: Dict, kinds: Callable = space_kind):
+    """The program's space of configuration ``cfg``, built by the kind
+    file its ``space`` block names (``spaces/<kind>.py``)."""
+    return kinds(cfg["space"].get("kind", "grid")).build(cfg["space"])
 
 
 def service_config(cfg: Dict, requests: List):
@@ -202,7 +192,7 @@ def prepare(cat: Catalog, workload: str, seed: int, seconds: float) -> Cell:
     cell = cat.cell(workload)
     wl = cat.workload(workload)
     cfg = cat.config(cell["config"])
-    space = design_space(cfg)
+    space = design_space(cfg, cat.space_kind)
     n = space.size()
     plan = traffic.open_plan(workload, wl, seconds, seed, n,
                              cat.request_kind)
@@ -297,7 +287,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     # -- correctness, after the window and the service are done ------------
     t_check = time.perf_counter()
     nums, compared = check.compare(win.records, cat.request_kind,
-                                   Reference(cell.cfg["space"]), seed,
+                                   Reference(cell.cfg["space"],
+                                             kinds=cat.space_kind), seed,
                                    cell.wl.get("check", {}))
     nums["missing"] = win.unanswered
     correct, checks = check.verdict(nums, compared)

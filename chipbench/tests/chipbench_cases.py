@@ -80,3 +80,20 @@ def small_risk(rate: float = 40.0) -> dict:
                               "params": {"rows": {"choices": [16, 32, 64]},
                                          "mc": mc}}]},
             "check": {"responses": 8, "rows": 4}}
+
+
+def small_fig4(chunk: int = 64) -> dict:
+    """The Fig. 4 configuration with a 64-slot chunk (CPU-sized)."""
+    cfg = json.loads((BENCH / "configs" / "fig4_grid.json").read_text())
+    cfg["service"].update(chunk=chunk, split=chunk // 4)
+    return cfg
+
+
+def small_fig4_risk(rate: float = 40.0) -> dict:
+    """The Fig. 4 risk mix at a CPU-sized rate and 64 draws."""
+    wl = json.loads((BENCH / "workloads" / "fig4_grid.risk.json")
+                    .read_text())
+    wl["config"] = "fig4_small"
+    wl["open"]["rate_per_s"] = rate
+    wl["open"]["mix"][0]["params"]["mc"]["draws"] = 64
+    return wl

@@ -12,14 +12,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench_cases import small_points, small_risk, small_scms
+from chipbench_cases import small_fig4, small_fig4_risk, small_points, \
+    small_risk, small_scms
 
 from harness import check, runner
 from harness.catalog import Catalog
 from harness.reference import Reference
 from repro.service import server
 
-CELLS = {"scms_small.points": small_points, "scms_small.risk": small_risk}
+# cell -> (configuration, its file, the workload file)
+CELLS = {"scms_small.points": ("scms_small", small_scms, small_points),
+         "scms_small.risk": ("scms_small", small_scms, small_risk),
+         "fig4_small.risk": ("fig4_small", small_fig4, small_fig4_risk)}
 
 
 def _alter(x):
@@ -49,7 +53,8 @@ def _broken(orig, fn, mc):
 
 
 def _add(checkout, cell):
-    checkout.add_cell(cell, "scms_small", small_scms(), CELLS[cell]())
+    config, cfg, wl = CELLS[cell]
+    checkout.add_cell(cell, config, cfg(), wl())
 
 
 @pytest.mark.parametrize("cell", sorted(CELLS))
