@@ -28,7 +28,7 @@ Quickstart::
     print(res.best.label, res.best.portfolio_cost)
 """
 from .space import (ArchChoice, Candidate, CandidateEncoder, DesignSpace,
-                    EncoderMeta, ReuseChoice, SKU, candidate_systems,
+                    EncoderMeta, IODie, ReuseChoice, SKU, candidate_systems,
                     encode_arrays, encode_batch)
 from .evaluate import (CandidateResult, ChunkShape, ChunkedEvaluator,
                        EvalArrays, chunk_shape, evaluate_direct)
@@ -42,7 +42,7 @@ from .report import (detail_rows, format_table, result_rows, search_summary,
 
 __all__ = [
     "ArchChoice", "Candidate", "CandidateEncoder", "DesignSpace",
-    "EncoderMeta", "ReuseChoice", "SKU", "candidate_systems",
+    "EncoderMeta", "IODie", "ReuseChoice", "SKU", "candidate_systems",
     "encode_arrays", "encode_batch", "CandidateResult", "ChunkShape",
     "ChunkedEvaluator", "EvalArrays", "chunk_shape", "evaluate_direct",
     "SENSITIVITY_PARAMS", "Uncertainty", "mc_summary", "mc_totals",

@@ -63,9 +63,10 @@ def chunk_shape(space: DesignSpace, candidates_per_chunk: int) -> ChunkShape:
     """Upper-bound shapes for any ``candidates_per_chunk`` candidates.
 
     Per candidate: S systems (one per SKU), each at most ``max_chips``
-    chips; each chip carries one functional module and at most one D2D
-    module instance; chip/module design entities are bounded by the chip
-    instances, package entities by S, D2D entities by the process menu.
+    chips (an IO die counted); each chip carries one functional module
+    and at most one D2D module instance; chip/module design entities are
+    bounded by the chip instances, package entities by S, D2D entities by
+    the nodes of the process menu and of the IO dies.
     Entity tables get one slack row so padded instances always have a
     zero-NRE row to point at.  The vectorized encoder emits exactly this
     signature, so fused and host-packed chunks share one engine trace.
@@ -82,7 +83,7 @@ def chunk_shape(space: DesignSpace, candidates_per_chunk: int) -> ChunkShape:
         pkg_entities=k * s + 1,
         mod_entities=k * per_cand_chips + 1,
         mod_instances=k * per_cand_chips,
-        d2d_entities=k * len(space.processes) + 1,
+        d2d_entities=k * len(space.d2d_processes()) + 1,
         d2d_instances=k * per_cand_chips,
     )
 

@@ -15,6 +15,11 @@ whole portfolio).  ``candidate_systems`` lowers a candidate to the
 :class:`~repro.core.system.System` group that
 :class:`~repro.core.batch.SystemBatch` packs and the engine prices.
 
+A SKU may carry an :class:`IODie` (the paper's Fig. 5 compute/IO split):
+every multi-chip system of that SKU then holds its compute chiplets plus
+the IO die on the IO die's own node, and the monolithic SoC holds the
+compute and IO content on one die of the compute node.
+
 The space is countable: ``size()`` / ``candidate_at(i)`` give a total
 order, so exhaustive enumeration, uniform sampling and index-based
 decoding all agree — the property the seeded-determinism tests pin.
@@ -32,19 +37,35 @@ import numpy as np
 from ..core.batch import SystemBatch
 from ..core.engine import NREBreakdown
 from ..core.reuse import portfolio_reuse_systems
-from ..core.system import System, spec
+from ..core.system import Chip, Module, System, make_chip, spec
 from ..core.technology import node, tech
+from ..obs.trace import TRACER as _TRACER
 
 _REL_TOL = 1e-6
 
 
 @dataclasses.dataclass(frozen=True)
+class IODie:
+    """A SKU's IO die: one die of ``area_mm2`` on its own ``process``,
+    beside the compute chiplets of every multi-chip architecture.  Its
+    design is named by ``name`` and the integration: within a candidate,
+    the SKUs whose IO dies share both share one design."""
+
+    name: str
+    area_mm2: float
+    process: str
+
+
+@dataclasses.dataclass(frozen=True)
 class SKU:
-    """One product in the portfolio: a module inventory and its volume."""
+    """One product in the portfolio: a module inventory (the compute
+    content, which the architecture choices split), its volume and an
+    optional IO die."""
 
     name: str
     module_area_mm2: float
     quantity: float
+    io_die: Optional[IODie] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,6 +167,27 @@ class DesignSpace:
             tech(t)
         if not self.chiplet_counts or min(self.chiplet_counts) < 1:
             raise ValueError("chiplet_counts must be positive")
+        io_dies: Dict[str, IODie] = {}
+        for s in self.skus:
+            if s.io_die is None:
+                continue
+            node(s.io_die.process)
+            if io_dies.setdefault(s.io_die.name, s.io_die) != s.io_die:
+                raise ValueError(
+                    f"IO die {s.io_die.name!r} is one design: every SKU "
+                    "that names it must give the same area and node")
+
+    @property
+    def has_io_dies(self) -> bool:
+        return any(s.io_die is not None for s in self.skus)
+
+    def d2d_processes(self) -> Tuple[str, ...]:
+        """The D2D interface namespace of one candidate: the process menu,
+        then the IO dies' nodes that are not on it."""
+        extra = [s.io_die.process for s in self.skus
+                 if s.io_die is not None
+                 and s.io_die.process not in self.processes]
+        return tuple(self.processes) + tuple(dict.fromkeys(extra))
 
     # -- choice inventories (cached: the space is frozen, and the search
     # loop asks for them on every sample/mutate/crossover) -------------------
@@ -282,10 +324,15 @@ class DesignSpace:
 
     # -- batching bounds -----------------------------------------------------
     def max_chips(self) -> int:
-        """Widest system any candidate can produce (padding bound)."""
+        """Widest system any candidate can produce (padding bound), an IO
+        die counted beside the compute chiplets."""
+        io = [int(s.io_die is not None) for s in self.skus]
         m = max(self.chiplet_counts)
+        split = [n for n in self.chiplet_counts if n > 1]
+        if split:
+            m = max(m, max(split) + max(io))
         for r in self._reuse_choices:
-            m = max(m, max(self.reuse_counts(r)))
+            m = max(m, max(k + i for k, i in zip(self.reuse_counts(r), io)))
         return m
 
     # -- index algebra (inverse of candidate_at) ----------------------------
@@ -338,27 +385,59 @@ def candidate_systems(space: DesignSpace, cand: Candidate) -> List[System]:
             f"space has {len(space.skus)} SKUs")
     if cand.reuse is not None:
         r = cand.reuse
-        return portfolio_reuse_systems(
-            r.slice_area_mm2, r.process, r.integration,
-            counts=list(space.reuse_counts(r)),
-            quantities=[s.quantity for s in space.skus],
-            names=[s.name for s in space.skus],
-            package_reuse=r.package_reuse)
+        counts = space.reuse_counts(r)
+        systems = [_with_io_die(sys, sku, r.integration)
+                   for sys, sku in zip(portfolio_reuse_systems(
+                       r.slice_area_mm2, r.process, r.integration,
+                       counts=list(counts),
+                       quantities=[s.quantity for s in space.skus],
+                       names=[s.name for s in space.skus],
+                       package_reuse=r.package_reuse), space.skus)]
+        if r.package_reuse:
+            # the shared package is sized for the largest system's
+            # silicon, IO die included
+            area = (max(s.chips[0].area_mm2 * k
+                        + sum(c.area_mm2 for c in s.chips[k:])
+                        for s, k in zip(systems, counts))
+                    * tech(r.integration).package_area_factor)
+            systems = [dataclasses.replace(s, package_area_mm2=area)
+                       for s in systems]
+        return systems
     out = []
     for sku, c in zip(space.skus, cand.choices):
         if c.n_chiplets == 1:
+            # the monolithic SoC holds the IO content on the compute die
+            io = sku.io_die.area_mm2 if sku.io_die is not None else 0.0
             out.append(spec({"kind": "soc", "name": sku.name,
-                             "area": sku.module_area_mm2,
+                             "area": sku.module_area_mm2 + io,
                              "process": c.process,
                              "quantity": sku.quantity}))
         else:
-            out.append(spec({"kind": "split", "name": sku.name,
-                             "area": sku.module_area_mm2,
-                             "process": c.process, "n": c.n_chiplets,
-                             "integration": c.integration,
-                             "quantity": sku.quantity,
-                             "reuse_chiplet": space.reuse_within_sku}))
+            out.append(_with_io_die(spec({
+                "kind": "split", "name": sku.name,
+                "area": sku.module_area_mm2, "process": c.process,
+                "n": c.n_chiplets, "integration": c.integration,
+                "quantity": sku.quantity,
+                "reuse_chiplet": space.reuse_within_sku}), sku,
+                c.integration))
     return out
+
+
+def _io_chip(io: IODie, integration: str) -> Chip:
+    """The IO die as a chip under ``integration`` (its D2D module on its
+    own node).  One design per (IO die, integration)."""
+    name = f"{io.name}_{integration}"
+    return make_chip(name, [Module(f"{name}_modules", io.area_mm2,
+                                   io.process)], io.process,
+                     integration=integration)
+
+
+def _with_io_die(system: System, sku: SKU, integration: str) -> System:
+    """``system`` with ``sku``'s IO die after its compute chiplets."""
+    if sku.io_die is None:
+        return system
+    return dataclasses.replace(
+        system, chips=system.chips + (_io_chip(sku.io_die, integration),))
 
 
 # ---------------------------------------------------------------------------
@@ -369,14 +448,16 @@ def candidate_systems(space: DesignSpace, cand: Candidate) -> List[System]:
 # value is read off the *actual* System objects candidate_systems builds
 # (same float64 -> float32 cast as SystemBatch.from_systems), so the
 # encoded batch is bit-identical to the host-packed one.
-_CHOICE_TABLE_FIELDS = (
+_CHIP_TABLE_FIELDS = (
     # chip slots
-    "n_chips", "chip_area", "mod_area", "chip_defect", "wafer_cost",
+    "chip_area", "mod_area", "chip_defect", "wafer_cost",
     "cluster", "wafer_yield", "sort_cost", "bump_cost",
     # chip/module NRE coefficients
     "nre_chip_k", "nre_chip_fixed", "nre_mod_k",
     # D2D interface
     "has_d2d", "d2d_pidx",
+)
+_CHOICE_TABLE_FIELDS = ("n_chips",) + _CHIP_TABLE_FIELDS + (
     # per-system / package
     "package_area", "package_area_factor", "substrate_cost",
     "substrate_layer", "interposer_cost", "interposer_defect",
@@ -384,6 +465,11 @@ _CHOICE_TABLE_FIELDS = (
     "y3_substrate_bond", "assembly_yield", "bond_cost_per_chip",
     "pkg_k", "pkg_fixed",
 )
+# The IO die's columns, in spaces whose SKUs carry one: whether the system
+# holds it (in slot n, after its n compute dies), the id of its design
+# (equal ids are one design; -1 where there is none) and its chip fields.
+_IO_TABLE_FIELDS = ("io_chips", "io_design") + tuple(
+    "io_" + f for f in _CHIP_TABLE_FIELDS)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -399,6 +485,10 @@ class EncoderMeta:
     n_arch: int              # A ** n_skus (first reuse index)
     size: int                # total candidate count
     reuse_within_sku: bool
+    # Systems may hold an IO die in slot n.  Where none can, the programs
+    # leave the IO terms out: they add only zeros there, yet took an
+    # IO-less chunk program to 1.54x its device time on a TPU v5e.
+    io_dies: bool = False
 
 
 class CandidateEncoder:
@@ -413,11 +503,17 @@ class CandidateEncoder:
     zero per-candidate Python, which is what moves the DSE inner loop
     on-device (see :mod:`repro.dse.evaluate` / ``search``).
 
+    Every system is ``n`` copies of one compute die, plus at most one IO
+    die in slot ``n``: the compute die has one set of columns and, in a
+    space whose SKUs carry IO dies, the IO die a second (``io_*``).
+
     The NRE entity layout is canonical rather than discovery-ordered:
     candidate ``j`` owns chip/module entity rows ``1 + j*S*C .. ``,
     package rows ``1 + j*S ..`` and D2D rows ``1 + j*P ..`` (row 0 of
-    every table is a shared zero-NRE sink for padded slots).  Shapes
-    match :func:`repro.dse.evaluate.chunk_shape` exactly, so encoded and
+    every table is a shared zero-NRE sink for padded slots).  An IO die's
+    chip/module row is its own slot in the first system of the candidate
+    that holds the same IO design.  Shapes match
+    :func:`repro.dse.evaluate.chunk_shape` exactly, so encoded and
     host-packed chunks share one compiled engine trace.
     """
 
@@ -426,17 +522,26 @@ class CandidateEncoder:
             raise ValueError(
                 f"space has {space.size()} candidates; the int32 index "
                 "encoding supports at most 2**31 - 1")
+        with _TRACER.span("encoder_build"):
+            self._build(space)
+
+    def _build(self, space: DesignSpace):
         self.space = space
         s, c = len(space.skus), space.max_chips()
         a, r = len(space._arch_choices), len(space._reuse_choices)
-        p = len(space.processes)
+        self._d2d = space.d2d_processes()
         self.meta = EncoderMeta(
             n_skus=s, max_chips=c, n_arch_choices=a, n_reuse_choices=r,
-            n_processes=p, n_arch=a ** s, size=space.size(),
-            reuse_within_sku=space.reuse_within_sku)
+            n_processes=len(self._d2d), n_arch=a ** s, size=space.size(),
+            reuse_within_sku=space.reuse_within_sku,
+            io_dies=space.has_io_dies)
 
-        tab = {f: np.zeros((s, a + r), np.float32)
-               for f in _CHOICE_TABLE_FIELDS}
+        fields = _CHOICE_TABLE_FIELDS + (_IO_TABLE_FIELDS if
+                                         space.has_io_dies else ())
+        tab = {f: np.zeros((s, a + r), np.float32) for f in fields}
+        if space.has_io_dies:
+            tab["io_design"][:] = -1.0
+        self._io_designs: Dict[str, int] = {}
         pkg_shared = np.zeros((a + r,), np.float32)
         for e in range(a + r):
             if e < a:
@@ -447,31 +552,32 @@ class CandidateEncoder:
                 cand = Candidate(reuse=ch)
             for i, sys in enumerate(candidate_systems(space, cand)):
                 self._fill(tab, i, e, sys)
+        # chips of each candidate on the host, for admission counts: the
+        # sums over the high and the low half of the SKU digits, and over
+        # each reuse scheme's systems
+        chips = (tab["n_chips"] + tab.get("io_chips", 0.0)).astype(np.int64)
+        h = s // 2
+        self._lo_base = a ** h
+        self._hi_chips = _digit_sums(chips[:s - h, :a])
+        self._lo_chips = _digit_sums(chips[s - h:, :a])
+        self._reuse_chips = chips[:, a:].sum(0)
         self.tables: Dict[str, jnp.ndarray] = {
             k: jnp.asarray(v) for k, v in tab.items()}
         self.tables["pkg_shared"] = jnp.asarray(pkg_shared)
-        # static per-process D2D NRE menu (row values are candidate-free)
+        # static per-node D2D NRE menu (row values are candidate-free)
         self.tables["d2d_nre"] = jnp.asarray(
-            [node(p_).nre_d2d for p_ in space.processes], jnp.float32)
+            [node(p_).nre_d2d for p_ in self._d2d], jnp.float32)
         self.tables["quantity"] = jnp.asarray(
             [sk.quantity for sk in space.skus], jnp.float32)
         # mixed-radix digit extractors, SKU 0 most significant
         self.tables["digit_pow"] = jnp.asarray(
             [a ** (s - 1 - i) for i in range(s)], jnp.int32)
 
-    def _fill(self, tab, i: int, e: int, sys: System):
-        chip = sys.chips[0]
-        for other in sys.chips[1:]:     # even slices / reuse copies only
-            if (other.area_mm2 != chip.area_mm2
-                    or other.process != chip.process):
-                raise ValueError(
-                    f"encoder requires homogeneous chips per system; "
-                    f"{sys.name} mixes designs")
-        nd, t = chip.node, sys.tech
-        d2d = [m for m in chip.modules if m.is_d2d]
-        v = {
-            "n_chips": sys.n_chips, "chip_area": chip.area_mm2,
-            "mod_area": chip.module_area_mm2,
+    def _chip_values(self, chip: Chip) -> Dict[str, float]:
+        nd = chip.node
+        d2d = any(m.is_d2d for m in chip.modules)
+        return {
+            "chip_area": chip.area_mm2, "mod_area": chip.module_area_mm2,
             "chip_defect": chip.defect_density,
             "wafer_cost": nd.wafer_cost, "cluster": nd.cluster_param,
             "wafer_yield": nd.wafer_yield, "sort_cost": nd.wafer_sort_cost,
@@ -480,8 +586,25 @@ class CandidateEncoder:
             "nre_chip_fixed": nd.nre_fixed_per_chip,
             "nre_mod_k": nd.nre_module_per_mm2,
             "has_d2d": 1.0 if d2d else 0.0,
-            "d2d_pidx": (self.space.processes.index(chip.process)
-                         if d2d else 0),
+            "d2d_pidx": self._d2d.index(chip.process) if d2d else 0,
+        }
+
+    def _fill(self, tab, i: int, e: int, sys: System):
+        chips, io = sys.chips, None
+        if self.space.skus[i].io_die is not None and sys.n_chips > 1:
+            chips, io = chips[:-1], chips[-1]
+        chip = chips[0]
+        for other in chips[1:]:     # even slices / reuse copies only
+            if (other.area_mm2 != chip.area_mm2
+                    or other.process != chip.process):
+                raise ValueError(
+                    "the encoder takes n copies of one compute die plus at "
+                    f"most one IO die per system; {sys.name} holds compute "
+                    "dies of different areas or nodes, and a system of "
+                    "general mixed dies cannot be encoded")
+        t = sys.tech
+        v = {
+            "n_chips": len(chips), **self._chip_values(chip),
             "package_area": sys.package_area,
             "package_area_factor": t.package_area_factor,
             "substrate_cost": t.substrate_cost_per_mm2,
@@ -497,6 +620,12 @@ class CandidateEncoder:
             "pkg_k": t.nre_package_per_mm2,
             "pkg_fixed": t.nre_fixed_per_package,
         }
+        if io is not None:
+            v["io_chips"] = 1.0
+            v["io_design"] = self._io_designs.setdefault(
+                io.name, len(self._io_designs))
+            v.update({"io_" + k: x for k, x in
+                      self._chip_values(io).items()})
         for k, val in v.items():
             tab[k][i, e] = val
 
@@ -504,6 +633,31 @@ class CandidateEncoder:
         """Lower a ``(K,)`` int vector of candidate indices to a padded
         ``SystemBatch`` (one NRE group per candidate) — pure jnp."""
         return encode_arrays(self.tables, self.meta, idx)
+
+    def chip_slots(self, idx) -> Tuple[int, int]:
+        """``(chips, slots)`` of candidates ``idx``: the real chips of
+        their systems and the chip slots the chunk programs give them
+        (``S * max_chips`` each), on the host, with no device work."""
+        m = self.meta
+        idx = np.asarray(idx, np.int64)
+        slots = int(idx.size) * m.n_skus * m.max_chips
+        reuse = idx >= m.n_arch
+        chips = 0
+        if reuse.any():
+            chips += int(self._reuse_chips[idx[reuse] - m.n_arch].sum())
+            idx = idx[~reuse]
+        hi, lo = np.divmod(idx, self._lo_base)
+        chips += int(self._hi_chips[hi].sum() + self._lo_chips[lo].sum())
+        return chips, slots
+
+
+def _digit_sums(rows: np.ndarray) -> np.ndarray:
+    """Sum of ``rows[i, digit_i]`` for every mixed-radix number of
+    ``len(rows)`` digits (row 0 most significant), in index order."""
+    out = np.zeros((1,), np.int64)
+    for r in rows:
+        out = (out[:, None] + r[None, :]).reshape(-1)
+    return out
 
 
 def _decode(tables: Dict[str, jnp.ndarray], meta: EncoderMeta, idx):
@@ -518,6 +672,16 @@ def _decode(tables: Dict[str, jnp.ndarray], meta: EncoderMeta, idx):
     r = jnp.where(is_reuse, idx - meta.n_arch, 0)
     ext = jnp.where(is_reuse[:, None], a + r[:, None], digits)       # (K,S)
     return is_reuse, ext
+
+
+def _io_shared(tables: Dict[str, jnp.ndarray], ext):
+    """(K, S, S) bool: system ``j`` of a candidate holds the IO design of
+    system ``i`` (``[k, i, j]``) — the IO die's sharing set, an equality
+    reduce over the candidate's S systems."""
+    srange = jnp.arange(ext.shape[1], dtype=jnp.int32)
+    d = tables["io_design"][srange[None, :], ext]
+    held = tables["io_chips"][srange[None, :], ext] > 0.0
+    return (d[:, :, None] == d[:, None, :]) & held[:, None, :]
 
 
 def encode_arrays(tables: Dict[str, jnp.ndarray], meta: EncoderMeta,
@@ -539,12 +703,23 @@ def encode_arrays(tables: Dict[str, jnp.ndarray], meta: EncoderMeta,
         """(K, S) per-system gather, flattened to (N,)."""
         return tables[name][srange[None, :], ext].reshape(n)
 
-    n_chips = g("n_chips")
+    n_chips = g("n_chips")                          # compute dies
     mask = (jnp.arange(c, dtype=jnp.float32)[None, :]
             < n_chips[:, None]).astype(jnp.float32)                  # (N,C)
+    io_slot = None
+    if meta.io_dies:
+        comp_mask = mask
+        io_slot = ((jnp.arange(c, dtype=jnp.float32)[None, :]
+                    == n_chips[:, None])
+                   & (g("io_chips")[:, None] > 0.0))                 # (N,C)
+        mask = comp_mask + io_slot.astype(jnp.float32)
 
     def chip(name, pad=0.0):
-        val = g(name)[:, None] * mask
+        if io_slot is None:
+            val = g(name)[:, None] * mask
+        else:
+            val = jnp.where(io_slot, g("io_" + name)[:, None],
+                            g(name)[:, None] * comp_mask)
         return val if pad == 0.0 else val + pad * (1.0 - mask)
 
     # -- canonical NRE entity layout (see class docstring) -----------------
@@ -556,9 +731,16 @@ def encode_arrays(tables: Dict[str, jnp.ndarray], meta: EncoderMeta,
     sku_row = 1 + (sys_i * c)[:, None] + 0 * slot
     cand_row = 1 + (cand_of_sys * (s * c))[:, None] + 0 * slot
     arch_row = sku_row if meta.reuse_within_sku else own_row
-    chip_ids = jnp.where(mask > 0.0,
-                         jnp.where(is_reuse_sys[:, None], cand_row,
-                                   arch_row), 0).astype(jnp.int32)
+    rows = jnp.where(is_reuse_sys[:, None], cand_row, arch_row)
+    if io_slot is not None:
+        # the IO die's own slot in the first system holding its design
+        first = jnp.argmax(_io_shared(tables, ext), axis=-1)         # (K,S)
+        n_first = jnp.take_along_axis(
+            n_chips.reshape(k, s).astype(jnp.int32), first, axis=1)
+        io_row = 1 + ((jnp.arange(k, dtype=jnp.int32)[:, None] * s
+                       + first) * c + n_first).reshape(n)
+        rows = jnp.where(io_slot, io_row[:, None], rows)
+    chip_ids = jnp.where(mask > 0.0, rows, 0).astype(jnp.int32)
 
     def ent(values_2d):
         """Prefix a zero sink row and flatten (N, C) slot values."""
@@ -571,12 +753,15 @@ def encode_arrays(tables: Dict[str, jnp.ndarray], meta: EncoderMeta,
                         1 + sys_i).astype(jnp.int32)
 
     inst_sys = jnp.repeat(sys_i, c)                                  # (N*C,)
-    has_d2d = (g("has_d2d")[:, None] * mask) > 0.0
-    d2d_ids = jnp.where(
-        has_d2d,
-        1 + (cand_of_sys * p)[:, None] + g("d2d_pidx").astype(jnp.int32)[
-            :, None] + 0 * slot,
-        0).astype(jnp.int32)
+    if io_slot is None:
+        has_d2d = (g("has_d2d")[:, None] * mask) > 0.0
+        d2d_row = (1 + (cand_of_sys * p)[:, None]
+                   + g("d2d_pidx").astype(jnp.int32)[:, None] + 0 * slot)
+    else:
+        has_d2d = chip("has_d2d") > 0.0
+        d2d_row = (1 + (cand_of_sys * p)[:, None]
+                   + chip("d2d_pidx").astype(jnp.int32))
+    d2d_ids = jnp.where(has_d2d, d2d_row, 0).astype(jnp.int32)
 
     quantity = jnp.tile(tables["quantity"], k)
     zero1 = jnp.zeros((1,), jnp.float32)
@@ -643,9 +828,13 @@ def encoded_nre(tables: Dict[str, jnp.ndarray], meta: EncoderMeta,
       (``reuse_within_sku=False``: ``n`` distinct designs, ``n*NRE_e/q``);
     * cross-SKU reuse: one design over ``sum_s q_s * n_s`` uses;
     * packages: own design over ``q`` (shared: over ``sum_s q_s``);
-    * D2D: one interface per (candidate, process) over the
+    * D2D: one interface per (candidate, node) over the
       ``q_s * n_s`` of the SKUs that use it (a one-hot reduce over the
-      P-wide process menu, not a scatter).
+      P-wide node namespace, not a scatter), an IO die's one use counted
+      at its own node;
+    * IO dies: one design per (IO die, integration) over the ``q_s`` of
+      the candidate's SKUs that hold it (an equality reduce over the S
+      systems, :func:`_io_shared`).
 
     Returns the engine's :class:`~repro.core.engine.NREBreakdown` with
     ``(K * S,)`` fields, matching ``CostEngine.nre`` on the same encoded
@@ -682,15 +871,34 @@ def encoded_nre(tables: Dict[str, jnp.ndarray], meta: EncoderMeta,
     packages = jnp.where(shared, pkg_nre / denom_p,
                          pkg_nre / jnp.maximum(q, eps))
 
-    # D2D interfaces: one per (candidate, process) across the candidate
+    # D2D interfaces: one per (candidate, node) across the candidate
     has = g("has_d2d")
     pidx = g("d2d_pidx").astype(jnp.int32)
     w = has * q * n                                          # (K, S) uses
     onehot = (pidx[:, :, None]
               == jnp.arange(p, dtype=jnp.int32)[None, None, :])
     denom_d = (w[:, :, None] * onehot).sum(1)                # (K, P)
+    if meta.io_dies:
+        io_has = g("io_chips")
+        io_d2d = g("io_has_d2d") * io_has
+        io_p = g("io_d2d_pidx").astype(jnp.int32)
+        io_onehot = (io_p[:, :, None]
+                     == jnp.arange(p, dtype=jnp.int32)[None, None, :])
+        denom_d = denom_d + ((io_d2d * q)[:, :, None] * io_onehot).sum(1)
     den_sys = jnp.take_along_axis(denom_d, pidx, axis=1)     # (K, S)
     d2d = has * n * tables["d2d_nre"][pidx] / jnp.maximum(den_sys, eps)
+
+    if meta.io_dies:
+        # IO die designs over the SKUs of the candidate that hold them
+        denom_io = jnp.maximum(
+            (_io_shared(tables, ext) * q[:, None, :]).sum(-1), eps)
+        chips = chips + io_has * (
+            g("io_nre_chip_k") * g("io_chip_area")
+            + g("io_nre_chip_fixed")) / denom_io
+        modules = modules + io_has * (
+            g("io_nre_mod_k") * g("io_mod_area")) / denom_io
+        d2d = d2d + io_d2d * tables["d2d_nre"][io_p] / jnp.maximum(
+            jnp.take_along_axis(denom_d, io_p, axis=1), eps)
 
     flat = k * s
     return NREBreakdown(modules=modules.reshape(flat),

@@ -60,10 +60,13 @@ _TRACE_KEYS = ("fused_chunk", "fused_chunk_mc", "gen_step", "re", "nre",
 def space_fingerprint(space: DesignSpace) -> str:
     """Stable digest of a space definition — the cache namespace.
 
-    Two structurally identical spaces (same SKUs/menus/flags) fingerprint
-    identically regardless of object identity."""
+    Two structurally identical spaces (same SKUs, IO dies, menus, flags)
+    fingerprint identically regardless of object identity; a SKU without
+    an IO die hashes as it did before IO dies existed."""
     payload = {
         "skus": [[s.name, s.module_area_mm2, s.quantity]
+                 + ([s.io_die.name, s.io_die.area_mm2, s.io_die.process]
+                    if s.io_die is not None else [])
                  for s in space.skus],
         "processes": list(space.processes),
         "integrations": list(space.integrations),

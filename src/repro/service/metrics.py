@@ -212,6 +212,8 @@ class ServiceMetrics:
         self.device_get_bytes = 0
         self.raw_pack_s = 0.0                # raw ticks: entry -> dispatched
         self.raw_packs = 0
+        self.chunk_chips = 0                 # chunk-lane rows: real chips
+        self.chunk_chip_slots = 0            # ... and their padded slots
         self.per_lane: Dict[str, LaneStats] = {}
         self.t_start = time.perf_counter()
 
@@ -303,6 +305,20 @@ class ServiceMetrics:
             wall_s)
         REGISTRY.counter("service_raw_packs",
                          help="raw ticks packed").inc()
+
+    def record_chunk_chips(self, chips: int, slots: int):
+        """Rows admitted to a chunk lane (``chunk`` or ``mc``): the real
+        chips of their systems and the chip slots the fused programs give
+        them (``S * max_chips`` a row).  Counted at admission, on the
+        host."""
+        self.chunk_chips += chips
+        self.chunk_chip_slots += slots
+        REGISTRY.counter("service_chunk_chips",
+                         help="real chips of rows admitted to the chunk "
+                              "lanes").inc(chips)
+        REGISTRY.counter("service_chunk_chip_slots",
+                         help="padded chip slots of rows admitted to the "
+                              "chunk lanes").inc(slots)
 
     # -- tick accounting -----------------------------------------------------
     def record_tick(self, lane_kind: str, slots: int, used: int,

@@ -860,6 +860,8 @@ class PricingService:
         for it in items:
             it.deadline_t = active.deadline_t
             it.trace_id = trace_id
+            if isinstance(it, SpanWork):        # the chunk and MC lanes
+                self.metrics.record_chunk_chips(*self.enc.chip_slots(it.idx))
         self._active[uid] = active
         if active.deadline_t is not None:
             self._deadline_count += 1
