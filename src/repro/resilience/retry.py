@@ -2,8 +2,9 @@
 
 Both are host-side and synchronous: the service tick loop is single-
 threaded by design (one lane, one dispatch, one ``device_get`` per
-tick), so the breaker needs no locking — it is a small state machine
-advanced by the tick that owns it.
+tick, at most one tick in flight), so the breaker needs no locking — it
+is a small state machine advanced by the ticks as they dispatch and
+land.
 """
 from __future__ import annotations
 
@@ -91,9 +92,9 @@ class CircuitBreaker:
             self.probes += 1
             self._emit("probe")
             return True
-        # half_open: one probe is already in flight this tick; the tick
-        # loop is serial so a second allow() before its verdict means
-        # the probe tick itself re-entered — let it through.
+        # half_open: one probe is already in flight; a second allow()
+        # before its verdict is the probe tick itself re-entering, or
+        # the one tick the loop dispatches behind it — let it through.
         return True
 
     def record_success(self):

@@ -198,6 +198,7 @@ class ServiceMetrics:
         self.n_errors = 0
         self.n_rejected = 0                  # backpressure rejections
         self.ticks = 0
+        self.ticks_overlapped = 0            # dispatched behind a flight
         self.slots_used = 0
         self.slots_total = 0
         self.gen_ticks = 0
@@ -322,12 +323,15 @@ class ServiceMetrics:
 
     # -- tick accounting -----------------------------------------------------
     def record_tick(self, lane_kind: str, slots: int, used: int,
-                    rows_priced: int, wall_s: float):
+                    rows_priced: int, wall_s: float,
+                    overlapped: bool = False):
         """One device tick.  ``gen`` lanes price their whole population
         every tick, so callers pass ``slots == used == rows_priced`` for
         them — search work counts toward occupancy and rows like every
-        other lane instead of being silently excluded."""
+        other lane instead of being silently excluded.  ``overlapped``:
+        the tick was dispatched while another tick was in flight."""
         self.ticks += 1
+        self.ticks_overlapped += overlapped
         self.busy_s += wall_s
         self.rows_priced += rows_priced
         self.slots_used += used
@@ -341,6 +345,9 @@ class ServiceMetrics:
         if lane_kind == "gen":
             self.gen_ticks += 1
         REGISTRY.counter("service_ticks", help="device ticks").inc()
+        REGISTRY.counter("service_ticks_overlapped",
+                         help="fused ticks dispatched while another tick "
+                              "was in flight").inc(overlapped)
         REGISTRY.counter("service_rows_priced",
                          help="candidate rows priced").inc(rows_priced)
         REGISTRY.counter(f"service_ticks_{lane_kind}").inc()
@@ -362,6 +369,7 @@ class ServiceMetrics:
             "latency_s": _quantiles([r.latency_s for r in ok]),
             "ttfr_s": _quantiles([r.ttfr_s for r in ok]),
             "ticks": self.ticks,
+            "ticks_overlapped": self.ticks_overlapped,
             "device_gets": self.device_get_calls,
             "gen_ticks": self.gen_ticks,
             "ticks_by_lane": {k: v.ticks for k, v in self.per_lane.items()},

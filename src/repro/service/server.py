@@ -169,6 +169,23 @@ class _Active:
     bill: Optional[Bill] = None
 
 
+@dataclasses.dataclass(eq=False)
+class _Flight:
+    """A fused tick (``chunk`` or ``mc`` lane) that is dispatched, the
+    copies of its outputs to the host started, and not yet landed."""
+
+    plan: TickPlan
+    overlapped: bool = False           # dispatched while another flew
+    chunk_idx: Optional[np.ndarray] = None   # the padded chunk, host side
+    dev: Optional[jax.Array] = None    # ... and on the device
+    out: Optional[Tuple] = None        # the program's outputs
+    err: Optional[Exception] = None    # why there are none
+    attempt: int = 0                   # the dispatch attempt that held
+
+
+_FUSED = ("chunk", "mc")
+
+
 def _risk_keys(quantiles: Tuple[float, ...]) -> Tuple[str, ...]:
     return ("mean", "std") + tuple(f"q{int(round(q * 100))}"
                                    for q in quantiles)
@@ -310,6 +327,7 @@ class PricingService:
         # bill includes its final tick's share (see _tick)
         self._tick_done: List[Callable] = []
         self._raw_parts: Optional[List[GroupWork]] = None
+        self._flight: Optional[_Flight] = None   # at most one between passes
         # -- durability (repro.service.durability) ----------------------
         self.dur = DurabilityStats()
         self.dcfg = self.cfg.durability
@@ -657,6 +675,7 @@ class PricingService:
                        f"drain deadline passed with "
                        f"{req.rows_done}/{req.n_rows} rows done")
         self.sched.clear()
+        self._flight = None
         self.flight.record("drain_abort")
         if FlightRecorder.auto_dump_dir() is not None:
             try:
@@ -713,10 +732,11 @@ class PricingService:
         self._active.clear()
         self._deadline_count = 0
         self.sched.clear()
+        self._flight = None
 
     async def _run(self):
         while True:
-            if not self.sched.has_work():
+            if not self.sched.has_work() and self._flight is None:
                 if not self._running:
                     break
                 self._wake.clear()
@@ -1244,23 +1264,78 @@ class PricingService:
     # ------------------------------------------------------------------
 
     def _tick(self) -> bool:
+        """One pass of the loop: it lands one tick, or two where a raw or
+        gen tick follows the fused tick in flight.
+
+        A fused tick (``chunk`` or ``mc`` lane) is dispatched, and the
+        fused tick after it is dispatched behind it before it lands, so
+        the device runs tick N+1 while the host fetches and scatters
+        tick N, admits at the loop's yield and packs tick N+2.  Between
+        passes at most one tick is in flight.  A raw or gen tick lands
+        the tick in flight first and then runs alone: the gen step needs
+        its own results, and the raw pack sheds groups on the host."""
         if self.faults and self._fire("crash") is not None:
             self._hard_crash()
             return False
-        if self._deadline_count:
-            now = time.perf_counter()
-            for w in self.sched.expire(now):
-                owner: _Active = w.owner
-                if owner.failed:
-                    continue
-                self.res.bump("deadline_rejected")
-                self._fail(owner, DEADLINE_EXCEEDED,
-                           f"deadline exceeded after "
-                           f"{(now - owner.rec.t_submit) * 1e3:.1f} ms "
-                           f"({owner.rows_done}/{owner.n_rows} rows done)")
-        plan = self.sched.plan()
-        if plan is None:
-            return False
+        self._expire()
+        flight = self._flight
+        if flight is None:
+            plan = self.sched.plan()
+            if plan is None:
+                return False
+            if plan.lane.kind not in _FUSED:
+                self._run_tick(plan, lambda: self._tick_sync(plan))
+                return True
+        else:
+            plan = flight.plan
+        after: Optional[TickPlan] = None
+
+        def fly_and_land() -> int:
+            nonlocal after
+            landing = flight or self._launch(plan)
+            nxt = self.sched.plan()
+            if nxt is not None and nxt.lane.kind in _FUSED:
+                self._flight = self._launch(nxt, overlapped=True)
+            else:
+                self._flight, after = None, nxt
+            return self._land(landing)
+
+        self._run_tick(plan, fly_and_land,
+                       overlapped=flight is not None and flight.overlapped)
+        if after is not None:
+            self._run_tick(after, lambda: self._tick_sync(after))
+        return True
+
+    def _expire(self):
+        """Fail every request past its deadline whose work is queued or
+        rides the tick in flight; that tick's other rows still land."""
+        if not self._deadline_count:
+            return
+        now = time.perf_counter()
+        late = [w.owner for w in self.sched.expire(now)]
+        if self._flight is not None:
+            late += [o for o in self._owners(self._flight.plan)
+                     if o.deadline_t is not None and o.deadline_t <= now]
+        for owner in late:
+            if owner.failed:
+                continue
+            self.res.bump("deadline_rejected")
+            self._fail(owner, DEADLINE_EXCEEDED,
+                       f"deadline exceeded after "
+                       f"{(now - owner.rec.t_submit) * 1e3:.1f} ms "
+                       f"({owner.rows_done}/{owner.n_rows} rows done)")
+
+    def _tick_sync(self, plan: TickPlan) -> int:
+        if plan.gen is not None:
+            return self._tick_gen(plan)
+        return self._tick_raw(plan)
+
+    def _run_tick(self, plan: TickPlan, body: Callable[[], int],
+                  overlapped: bool = False):
+        """Run ``body`` as the tick that lands ``plan``: its span, wall,
+        bill, counters and flight-recorder entry, then the completions
+        it found.  ``body`` returns the rows it priced; ``overlapped``
+        says ``plan`` was dispatched while another tick was in flight."""
         # terminal completions discovered during the tick are DEFERRED to
         # after the wall clock stops and the ledger charges the tick, so
         # a finishing request's bill includes its final tick's share.
@@ -1288,12 +1363,7 @@ class PricingService:
                 if stall is not None:
                     time.sleep(stall.ms / 1e3)
                 try:
-                    if plan.gen is not None:
-                        rows = self._tick_gen(plan)
-                    elif plan.lane.kind == "raw":
-                        rows = self._tick_raw(plan)
-                    else:
-                        rows = self._tick_chunk(plan)
+                    rows = body()
                 except Exception as e:  # fail the owners, keep serving
                     self._fail_tick(plan, e)
                     rows = 0
@@ -1317,10 +1387,12 @@ class PricingService:
                                 slots or 1, used,
                                 dispatch_s=dispatch_s,
                                 retries=self.res.retries - retries_before)
-        self.metrics.record_tick(plan.lane.kind, slots, used, rows, wall)
+        self.metrics.record_tick(plan.lane.kind, slots, used, rows, wall,
+                                 overlapped=overlapped)
         self.flight.record("tick", lane=plan.lane.kind, slots=slots,
                            used=used, rows=rows, wall_s=wall,
-                           recompiled=bool(recompiled))
+                           recompiled=bool(recompiled),
+                           overlapped=overlapped)
         if recompiled:
             self.log.event(-1, "tick_recompile", lane=plan.lane.kind,
                            traces=recompiled)
@@ -1329,7 +1401,6 @@ class PricingService:
             with _TRACER.span("finish"):
                 for fin in done:
                     fin()
-        return True
 
     def _tick_parts(self, plan: TickPlan) -> List[Tuple[Bill, int]]:
         """(bill, rows contributed) per request for this tick — the
@@ -1388,8 +1459,9 @@ class PricingService:
             self._fail(owner, INTERNAL_ERROR,
                        f"{type(err).__name__}: {err}")
 
-    def _dispatch_fused(self, lane: Lane, dev):
-        """One fused-kernel dispatch + host fetch (may raise)."""
+    def _launch_fused(self, lane: Lane, dev):
+        """One fused-program dispatch (may raise); the copies of its
+        outputs to the host are queued behind the program at once."""
         mc = lane.kind == "mc"
         if self.faults:
             if self._fire("recompile") is not None:
@@ -1409,7 +1481,9 @@ class PricingService:
         else:
             out = _CHUNK_JIT(self.enc.tables, dev, self.qty,
                              meta=self.enc.meta, flow=lane.flow)
-        return self._fetch(out)
+        for x in jax.tree_util.tree_leaves(out):
+            x.copy_to_host_async()
+        return out
 
     def _fetch(self, out):
         """THE tick sync: ``jax.device_get`` of the tick's outputs, its
@@ -1423,26 +1497,31 @@ class PricingService:
                 for x in jax.tree_util.tree_leaves(host)))
         return host
 
-    def _dispatch_fused_with_retry(self, lane: Lane, dev):
-        """Returns ``(host, None)`` or, with the retry budget spent,
-        ``(None, last_error)`` — the caller decides fallback vs raise."""
-        last: Optional[Exception] = None
-        for attempt in range(1 + max(0, self.cfg.tick_retries)):
+    def _retry(self, lane: Lane, call: Callable, first: int = 0,
+               last: Optional[Exception] = None):
+        """``call()`` under the tick's retry budget from attempt
+        ``first``: ``(value, None, attempt)`` or, with the budget spent,
+        ``(None, last_error, attempt)`` — the caller decides fallback vs
+        raise."""
+        attempt = first
+        for attempt in range(first, 1 + max(0, self.cfg.tick_retries)):
             if attempt:
                 self.res.bump("retries")
                 time.sleep(self.cfg.retry_backoff_s * attempt)
             try:
-                return self._dispatch_fused(lane, dev), None
+                return call(), None, attempt
             except Exception as e:  # noqa: BLE001 - retry any failure
-                self.res.bump("fused_failures")
+                self._fused_error(lane, attempt, e)
                 last = e
-                self.log.event(-1, "fused_dispatch_error", lane=lane.kind,
-                               attempt=attempt,
-                               error=f"{type(e).__name__}: {e}")
-                self.flight.record("fused_dispatch_error", lane=lane.kind,
-                                   attempt=attempt,
-                                   error=f"{type(e).__name__}: {e}")
-        return None, last
+        return None, last, attempt
+
+    def _fused_error(self, lane: Lane, attempt: int, err: Exception):
+        self.res.bump("fused_failures")
+        self.log.event(-1, "fused_dispatch_error", lane=lane.kind,
+                       attempt=attempt, error=f"{type(err).__name__}: {err}")
+        self.flight.record("fused_dispatch_error", lane=lane.kind,
+                           attempt=attempt,
+                           error=f"{type(err).__name__}: {err}")
 
     def _fallback_chunk_host(self, lane: Lane, chunk_idx: np.ndarray):
         """Degraded-mode tick: price the (already padded) chunk through
@@ -1466,39 +1545,72 @@ class PricingService:
         out.append(arrays.finite)
         return tuple(out)
 
-    def _tick_chunk(self, plan: TickPlan) -> int:
-        k = self.cfg.chunk
-        with _TRACER.span("pack", used=plan.used):
-            # int32 on the host: converting on the device would compile
-            # a convert program inside the first tick
-            chunk_idx = np.zeros((k,), np.int32)
-            for a in plan.assignments:
-                chunk_idx[a.slot:a.slot + a.n] = \
-                    a.item.idx[a.start:a.start + a.n]
-            if plan.used < k and plan.assignments:
-                chunk_idx[plan.used:] = chunk_idx[0]  # cost-neutral padding
-            dev = jnp.asarray(chunk_idx)
+    def _launch(self, plan: TickPlan, overlapped: bool = False) -> _Flight:
+        """Pack a fused plan's chunk and dispatch it under the retry
+        budget, without waiting for it.  Never raises: what stopped the
+        dispatch is kept for the landing."""
+        f = _Flight(plan=plan, overlapped=overlapped)
+        now = time.perf_counter()
+        for owner in self._owners(plan):
+            self.metrics.record_ride(owner.rec, now)
+        try:
+            with _TRACER.span("pack", used=plan.used):
+                # int32 on the host: converting on the device would
+                # compile a convert program inside the first tick
+                k = self.cfg.chunk
+                f.chunk_idx = np.zeros((k,), np.int32)
+                for a in plan.assignments:
+                    f.chunk_idx[a.slot:a.slot + a.n] = \
+                        a.item.idx[a.start:a.start + a.n]
+                if plan.used < k and plan.assignments:
+                    # cost-neutral padding
+                    f.chunk_idx[plan.used:] = f.chunk_idx[0]
+                f.dev = jnp.asarray(f.chunk_idx)
+            if self.breaker.allow():
+                with _TRACER.span("dispatch"):
+                    f.out, f.err, f.attempt = self._retry(
+                        plan.lane,
+                        lambda: self._launch_fused(plan.lane, f.dev))
+                if f.out is None:
+                    self.breaker.record_failure()
+        except Exception as e:  # noqa: BLE001 - fails at the landing
+            f.err = e
+        return f
+
+    def _land(self, f: _Flight) -> int:
+        """Fetch a flight's outputs in the tick's one ``device_get`` and
+        scatter them.  An error that surfaces here re-dispatches the
+        same chunk synchronously with what is left of the retry budget;
+        then the breaker and the fallback decide, as at the dispatch."""
+        lane = f.plan.lane
         host = None
-        degraded = False
-        if self.breaker.allow():
-            host, err = self._dispatch_fused_with_retry(plan.lane, dev)
+        if f.out is not None:
+            try:
+                host = self._fetch(f.out)
+            except Exception as e:  # noqa: BLE001 - retry any failure
+                self._fused_error(lane, f.attempt, e)
+                host, f.err, _ = self._retry(
+                    lane, lambda: self._fetch(self._launch_fused(lane, f.dev)),
+                    first=f.attempt + 1, last=e)
             if host is None:
                 self.breaker.record_failure()
-                if not self.cfg.fallback:
-                    raise err
-            else:
+            elif self.breaker.state != "open":
+                # open here means a newer tick failed after this one
+                # flew: its success is older news and closes nothing
                 self.breaker.record_success()
-        if host is None:
+        if host is None and f.err is not None and not self.cfg.fallback:
+            raise f.err
+        degraded = host is None
+        if degraded:
             # fused path down (or breaker open): slow-but-correct.
             t_fb = time.perf_counter()
-            host = self._fallback_chunk_host(plan.lane, chunk_idx)
-            degraded = True
+            host = self._fallback_chunk_host(lane, f.chunk_idx)
             self.res.bump("fallback_ticks")
-            self.res.bump("fallback_rows", plan.used)
+            self.res.bump("fallback_rows", f.plan.used)
             self.res.bump("fallback_busy_s", time.perf_counter() - t_fb)
         with _TRACER.span("scatter"):
-            self._scatter_chunk(plan, host, degraded)
-        return plan.used
+            self._scatter_chunk(f.plan, host, degraded)
+        return f.plan.used
 
     def _scatter_chunk(self, plan: TickPlan, host, degraded: bool):
         """Copy each assignment's rows of the fetched chunk into its

@@ -130,6 +130,7 @@ EXPECTED_KEYS = {
     "padded_waste_frac", "rows_priced", "busy_s", "rows_per_sec_busy",
     "wall_s", "admit_s", "admitted", "queue_wait_s", "queue_waited",
     "device_wait_s", "device_get_bytes", "raw_pack_s", "raw_packs",
+    "ticks_overlapped",
 }
 
 
